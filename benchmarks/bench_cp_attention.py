@@ -27,6 +27,7 @@ repo root.  Numbers are per-device ring-model bytes (post-SPMD HLO).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import functools
 import json
@@ -39,6 +40,7 @@ import numpy as np
 from repro.analysis import hlo_gates
 from repro.dist import context as cpx
 from repro.roofline import analysis as ra
+from repro.launch.mesh import make_mesh
 
 B, S, H, KV, D = 2, 64, 8, 4, 16
 CHUNKS = 4
@@ -56,7 +58,7 @@ def cp_hlo(cp: int, mode: str, chunks: int = 1) -> str:
     """Post-SPMD HLO of one cp_attention forward on a (cp,)-device
     ``seq`` mesh.  impl='ref' — the gates assert collective structure,
     which the in-shard kernel tier does not change."""
-    mesh = jax.make_mesh((cp,), ("seq",))
+    mesh = make_mesh((cp,), ("seq",))
     q, k, v = make_qkv()
     f = jax.jit(functools.partial(
         cpx.cp_attention, mesh=mesh, mode=mode, impl="ref",

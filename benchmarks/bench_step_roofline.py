@@ -25,6 +25,7 @@ repo root.  Numbers are per-device (post-SPMD HLO shapes are local).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import json
 import sys
@@ -44,6 +45,7 @@ from repro.models.common import init_params
 from repro.models.model import build_model
 from repro.optim import adamw
 from repro.train import step as step_mod
+from repro.launch.mesh import make_mesh
 
 GB, S, PP, TP = 8, 32, 4, 2
 # dims chosen so the vocab shard (256) / full vocab (1024) / d_ff (160)
@@ -87,7 +89,7 @@ def _gate(name: str, programs: dict, symbols=None) -> dict:
 
 def vp_ce_claim() -> dict:
     """Unembed dot FLOPs no longer scale with pp (gate: vp_ce)."""
-    mesh = jax.make_mesh((2, PP), ("data", "pipe"))
+    mesh = make_mesh((2, PP), ("data", "pipe"))
     m = _gate("vp_ce",
               {"masked": pp_grad_hlo(CFG, mesh, vocab_parallel=False),
                "vp": pp_grad_hlo(CFG, mesh, vocab_parallel=True)})
@@ -100,8 +102,8 @@ def tp_in_stage_claim() -> dict:
     """TP inside the stage bodies shards the FFN compute (gate:
     tp_in_stage; per-sample normalization for the dp-2 vs dp-1 meshes
     lives in the gate's num_scale/den_scale)."""
-    m1 = jax.make_mesh((2, 2, 1), ("data", "pipe", "model"))
-    m2 = jax.make_mesh((1, 2, TP), ("data", "pipe", "model"))
+    m1 = make_mesh((2, 2, 1), ("data", "pipe", "model"))
+    m2 = make_mesh((1, 2, TP), ("data", "pipe", "model"))
     m = _gate("tp_in_stage",
               {"tp1": pp_grad_hlo(CFG, m1, vocab_parallel=True),
                "tp2": pp_grad_hlo(CFG, m2, vocab_parallel=True)})
@@ -141,7 +143,7 @@ def grad_reduce_hlo(method: str) -> str:
     g = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32),
         build_model(cfg, impl="ref").param_shapes())
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
 
     if method == "none":
         def reduce_fn(grads, _ef):

@@ -36,6 +36,7 @@ def _realized_rows() -> list:
     script = Path(__file__).with_name("bench_vlm_realized.py")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env.setdefault("JAX_PLATFORMS", "cpu")
     src = str(Path(__file__).resolve().parents[1] / "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, str(script)],

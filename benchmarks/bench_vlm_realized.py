@@ -20,6 +20,7 @@ TIMELINE:
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import json
 import sys

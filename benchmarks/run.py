@@ -52,6 +52,7 @@ def vlm_realized(smoke: bool) -> None:
     """Run bench_vlm_realized in its own interpreter (it needs 8 virtual
     devices) and record the JSON at the repo root."""
     env = dict(os.environ, PYTHONPATH=str(_ROOT / "src"))
+    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, str(_ROOT / "benchmarks" /
                                "bench_vlm_realized.py")]
     if smoke:
@@ -77,6 +78,7 @@ def step_roofline() -> None:
     and record the scoreboard at the repo root.  The bench asserts the
     perf claims itself; a regression fails this command."""
     env = dict(os.environ, PYTHONPATH=str(_ROOT / "src"))
+    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, str(_ROOT / "benchmarks" /
                                "bench_step_roofline.py")]
     proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
@@ -105,6 +107,7 @@ def cp_attention() -> None:
     comm claims via the cp_overlap / ulysses_mqa gate files; a
     regression fails this command."""
     env = dict(os.environ, PYTHONPATH=str(_ROOT / "src"))
+    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, str(_ROOT / "benchmarks" /
                                "bench_cp_attention.py")]
     proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
@@ -146,6 +149,7 @@ def lint() -> None:
     """Run the static-analysis suite in its own interpreter (same entry
     point as ``python -m repro.analysis``)."""
     env = dict(os.environ, PYTHONPATH=str(_ROOT / "src"))
+    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run([sys.executable, "-m", "repro.analysis"],
                           env=env, timeout=900)
     sys.exit(proc.returncode)

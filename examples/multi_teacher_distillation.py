@@ -12,6 +12,7 @@ wavefront scheduler groups specialist samples into fewer microbatches,
 and all-generalist microbatches never touch the specialist's mesh."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np

@@ -18,13 +18,14 @@ from repro.models.model import build_model
 from repro.optim import adamw, schedules
 from repro.train import step as step_mod
 from repro.train.loop import train
+from repro.launch.mesh import make_mesh
 
 
 def main():
     cfg = ArchConfig(name="quickstart-lm", family="dense", num_layers=4,
                      d_model=256, num_heads=4, num_kv_heads=2, d_ff=704,
                      vocab_size=2048, head_dim=64, dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("quickstart", "train", 128, 8)
     model = build_model(cfg)
     step, shardings = step_mod.build_train_step(

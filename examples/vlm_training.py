@@ -21,6 +21,7 @@ by the compound executor with wavefront-scheduled microbatch dispatch.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import functools
 

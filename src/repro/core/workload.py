@@ -498,7 +498,7 @@ class CompoundRuntime:
     all thin declarations over this class."""
 
     def __init__(self, spec: WorkloadSpec, *, devices=None,
-                 impl: str = "ref", lr_schedule=None,
+                 impl: str = "auto", lr_schedule=None,
                  opt_cfg: adamw.AdamWConfig = adamw.AdamWConfig(),
                  lookahead: int = 0):
         spec.validate()
@@ -653,9 +653,9 @@ class CompoundRuntime:
         """Init + place every section's params (spec order rng split) and
         matching optimizer states for the trainable sections."""
         rngs = jax.random.split(rng, len(self.spec.sections))
-        host = {s.name: cm.init_params(s.params, r)
-                for s, r in zip(self.spec.sections, rngs)}
-        return self.place(host)
+        drawn = {s.name: cm.init_params(s.params, r, self._p_shard[s.name])
+                 for s, r in zip(self.spec.sections, rngs)}
+        return self.place(drawn)
 
     def place(self, params: Dict[str, Any]):
         """Place per-section param trees onto the carved meshes and build
@@ -992,7 +992,7 @@ class CompoundRuntime:
             ct_keys = self._ct_keys(s)
 
             def call(fn, params, inputs, _hook=hook, _ctx=ctx):
-                with cm.act_hook(_hook), _ctx():
+                with cm.act_hook(*_hook), _ctx():
                     return fn(params, inputs)
 
             if s.critical:
@@ -1101,11 +1101,9 @@ class CompoundRuntime:
         """Trace + compile every worker-thread jit from the main thread:
         the act-hook / attention-impl globals are process-wide, so
         concurrent first-call tracing from two section workers races."""
-        params = {}
-        for i, s in enumerate(self.spec.sections):
-            params[s.name] = jax.device_put(
-                cm.init_params(s.params, jax.random.PRNGKey(i)),
-                self._p_shard[s.name])
+        params = {s.name: cm.init_params(s.params, jax.random.PRNGKey(i),
+                                         self._p_shard[s.name])
+                  for i, s in enumerate(self.spec.sections)}
         outs = []
         for name in self._topo:
             s = by_name[name]
@@ -1147,10 +1145,9 @@ class CompoundRuntime:
         # ``upd`` dispatch): trace + compile the ssq and AdamW-update jits
         # here with dummy (donated) state so no worker ever traces
         for name in self._trainable:
-            gs = jax.device_put(
-                jax.tree_util.tree_map(
-                    lambda x: jnp.zeros(x.shape, x.dtype), params[name]),
-                self._p_shard[name])
+            gs = jax.tree_util.tree_map(
+                lambda x, sh: jnp.zeros(x.shape, x.dtype, device=sh),
+                params[name], self._p_shard[name])
             st = jax.device_put(adamw.init(params[name]),
                                 self._o_shard[name])
             if name in self._compress:

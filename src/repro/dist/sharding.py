@@ -75,25 +75,15 @@ DEFAULT_RULES = {
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-portable ``AbstractMesh`` constructor (signature changed
-    between jax releases; tests build device-free meshes through this)."""
+    """Device-free mesh (tests and dry-runs build shardings on it)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` without replication checking
-    (``jax.shard_map``/``check_vma`` on jax ≥ 0.5,
-    ``jax.experimental.shard_map``/``check_rep`` before)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without replication checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------------------- #

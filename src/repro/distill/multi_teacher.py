@@ -210,7 +210,7 @@ def build_colocated_step(ta_cfg: ArchConfig, tb_cfg: ArchConfig,
     kl_impl = "ref" if impl == "ref" else "auto"
 
     def mb_loss(ps, pa, pb, w_a, w_b, mb, bidx, bval):
-        with cm.act_hook(hook):
+        with cm.act_hook(*hook):
             h_a = teacher_hidden(pa, ta_cfg, mb["tokens"], impl=impl)
             h_b = specialist_hidden(pb, tb_cfg, mb["tokens"][bidx], bval,
                                     impl=impl)

@@ -94,7 +94,7 @@ def distill_loss(params_s, s_cfg: ArchConfig, batch, h_teacher,
 # --------------------------------------------------------------------------- #
 def build_colocated_step(t_cfg: ArchConfig, s_cfg: ArchConfig, mesh: Mesh,
                          shape: ShapeConfig, parallel: ParallelConfig, *,
-                         alpha=0.5, temperature=2.0, impl="ref",
+                         alpha=0.5, temperature=2.0, impl="auto",
                          lr_schedule=None,
                          opt_cfg: adamw.AdamWConfig = adamw.AdamWConfig()):
     """One jit: teacher fwd (frozen) + student train step. Teacher unembed
@@ -138,7 +138,7 @@ def build_colocated_step(t_cfg: ArchConfig, s_cfg: ArchConfig, mesh: Mesh,
     rep = shd.replicated(mesh)
 
     def loss_fn(p_s, mb, params_t):
-        with cm.act_hook(hook), cp_ctx():
+        with cm.act_hook(*hook), cp_ctx():
             h_t = teacher_hidden(jax.lax.stop_gradient(params_t), t_cfg,
                                  mb["tokens"], impl=impl)
             w_t = (params_t["embed"].T if t_cfg.tie_embeddings
@@ -149,8 +149,8 @@ def build_colocated_step(t_cfg: ArchConfig, s_cfg: ArchConfig, mesh: Mesh,
             # belongs to the disaggregated / Pallas-TPU paths
             return distill_loss(p_s, s_cfg, mb, h_t, w_t, alpha=alpha,
                                 temperature=temperature, impl=impl,
-                                kl_impl="ref_naive" if impl == "ref"
-                                else "auto")
+                                kl_impl="ref_naive"
+                                if kops.resolve(impl) == "ref" else "auto")
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -197,7 +197,7 @@ def distill_spec(t_cfg: ArchConfig, s_cfg: ArchConfig, *,
                  teacher_parallel: ParallelConfig,
                  student_parallel: ParallelConfig,
                  alpha: float = 0.5, temperature: float = 2.0,
-                 impl: str = "ref") -> wl.WorkloadSpec:
+                 impl: str = "auto") -> wl.WorkloadSpec:
     """KD as a declaration: a forward-only teacher section emitting final
     hidden states, and the critical student section computing CE + KL
     with the teacher's (colocated) output layer as a const.  Left
@@ -250,7 +250,7 @@ class DistillRuntime:
     def __init__(self, t_cfg: ArchConfig, s_cfg: ArchConfig, *,
                  teacher_parallel: ParallelConfig,
                  student_parallel: ParallelConfig,
-                 devices=None, alpha=0.5, temperature=2.0, impl="ref",
+                 devices=None, alpha=0.5, temperature=2.0, impl="auto",
                  lr=1e-3):
         fanout = student_parallel.dp // teacher_parallel.dp
         assert teacher_parallel.dp * fanout == student_parallel.dp, \

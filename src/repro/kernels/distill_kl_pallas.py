@@ -8,9 +8,13 @@ exist in HBM — the kernel-level form of Maestro §3.1's "ship hidden
 states, not logits".
 
 VMEM working set per step: two weight tiles [D, block_v] (the dominant
-term) + two hidden tiles [block_t, D] + the [block_t, block_v] logit tiles.
-With D=4096, block_v=512, block_t=256, bf16: ≈ 2·4MB + 2·2MB + 2·0.25MB
-≈ 12.5 MB — fits v5e VMEM headroom at double buffering.
+term) and two hidden tiles [block_t, D], double-buffered, their f32
+copies for the matmuls, and the f32 [block_t, block_v] logit tiles.
+``_vmem_bytes`` counts it, and the block is halved until it fits
+``_VMEM_BUDGET``; the kernel's scoped VMEM limit is raised to match (v5e's
+default scoped limit is 16 MiB of 128 MiB).  At D=4096, bf16, block_t=256
+that is block_v=256: 2·2·(2+2) MiB of tiles, 2·(4+4) MiB of f32 copies
+and 2·0.25 MiB of logits, ≈ 32.5 MiB.
 
 Backward: custom VJP reusing the analytic chunked backward from
 ``distill_kl.py`` (dz_s = p_s − p_t etc.), which never materializes logits
@@ -27,8 +31,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import distill_kl as _dk
+from repro.kernels.lanes import col_to_row as _col_to_row
 
 NEG_INF = -1e30
+_VMEM_BUDGET = 48 * 2**20
 
 
 def _kernel(hs_ref, ws_ref, ht_ref, wt_ref,
@@ -55,28 +61,38 @@ def _kernel(hs_ref, ws_ref, ht_ref, wt_ref,
     zt = jax.lax.dot_general(ht, wt, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32) * inv_temp
 
-    ms_new = jnp.maximum(ms_ref[:, 0], jnp.max(zs, axis=1))
-    ls_ref[:, 0] = (ls_ref[:, 0] * jnp.exp(ms_ref[:, 0] - ms_new)
-                    + jnp.sum(jnp.exp(zs - ms_new[:, None]), axis=1))
-    ms_ref[:, 0] = ms_new
+    ms_prev = ms_ref[...]                                 # [bt, 1]
+    ms_new = jnp.maximum(ms_prev, jnp.max(zs, axis=1, keepdims=True))
+    ls_ref[...] = (ls_ref[...] * jnp.exp(ms_prev - ms_new)
+                   + jnp.sum(jnp.exp(zs - ms_new), axis=1, keepdims=True))
+    ms_ref[...] = ms_new
 
-    mt_new = jnp.maximum(mt_ref[:, 0], jnp.max(zt, axis=1))
-    corr = jnp.exp(mt_ref[:, 0] - mt_new)
-    pt = jnp.exp(zt - mt_new[:, None])
-    lt_ref[:, 0] = lt_ref[:, 0] * corr + jnp.sum(pt, axis=1)
-    ut_ref[:, 0] = ut_ref[:, 0] * corr + jnp.sum(pt * zt, axis=1)
-    us_ref[:, 0] = us_ref[:, 0] * corr + jnp.sum(pt * zs, axis=1)
-    mt_ref[:, 0] = mt_new
+    mt_prev = mt_ref[...]
+    mt_new = jnp.maximum(mt_prev, jnp.max(zt, axis=1, keepdims=True))
+    corr = jnp.exp(mt_prev - mt_new)
+    pt = jnp.exp(zt - mt_new)
+    lt_ref[...] = lt_ref[...] * corr + jnp.sum(pt, axis=1, keepdims=True)
+    ut_ref[...] = ut_ref[...] * corr + jnp.sum(pt * zt, axis=1,
+                                               keepdims=True)
+    us_ref[...] = us_ref[...] * corr + jnp.sum(pt * zs, axis=1,
+                                               keepdims=True)
+    mt_ref[...] = mt_new
 
     @pl.when(iv == nv - 1)
     def _finalize():
-        lse_s_ref[...] = ms_ref[:, 0] + jnp.log(
-            jnp.maximum(ls_ref[:, 0], 1e-30))
-        lse_t_ref[...] = mt_ref[:, 0] + jnp.log(
-            jnp.maximum(lt_ref[:, 0], 1e-30))
-        lt = jnp.maximum(lt_ref[:, 0], 1e-30)
-        e_t_ref[...] = ut_ref[:, 0] / lt
-        e_s_ref[...] = us_ref[:, 0] / lt
+        ls = jnp.maximum(ls_ref[...], 1e-30)
+        lt = jnp.maximum(lt_ref[...], 1e-30)
+        lse_s_ref[...] = _col_to_row(ms_ref[...] + jnp.log(ls))
+        lse_t_ref[...] = _col_to_row(mt_ref[...] + jnp.log(lt))
+        e_t_ref[...] = _col_to_row(ut_ref[...] / lt)
+        e_s_ref[...] = _col_to_row(us_ref[...] / lt)
+
+
+def _vmem_bytes(bt, bv, Ds, Dt, itemsize):
+    """Double-buffered input tiles, their f32 copies and the two f32
+    logit tiles."""
+    tiles = (Ds + Dt) * (bt + bv)
+    return 2 * tiles * itemsize + tiles * 4 + 2 * bt * bv * 4
 
 
 def _stats_pallas(h_s, w_s, h_t, w_t, *, temperature, block_t, block_v,
@@ -84,12 +100,13 @@ def _stats_pallas(h_s, w_s, h_t, w_t, *, temperature, block_t, block_v,
     N, Ds = h_s.shape
     Dt = h_t.shape[1]
     V = w_s.shape[1]
-    bt = min(block_t, N)
-    bv = min(block_v, V)
+    bt, bv = block_t, block_v
     assert N % bt == 0 and V % bv == 0, (N, V, bt, bv)
     grid = (N // bt, V // bv)
     kernel = functools.partial(_kernel, inv_temp=1.0 / temperature,
                                nv=V // bv)
+    vmem = _vmem_bytes(bt, bv, Ds, Dt, w_s.dtype.itemsize)
+    # statistics are written lane-dense as [1, N] rows
     outs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -99,12 +116,22 @@ def _stats_pallas(h_s, w_s, h_t, w_t, *, temperature, block_t, block_v,
             pl.BlockSpec((bt, Dt), lambda it, iv: (it, 0)),
             pl.BlockSpec((Dt, bv), lambda it, iv: (0, iv)),
         ],
-        out_specs=[pl.BlockSpec((bt,), lambda it, iv: (it,))] * 4,
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.float32)] * 4,
+        out_specs=[pl.BlockSpec((1, bt), lambda it, iv: (0, it))] * 4,
+        out_shape=[jax.ShapeDtypeStruct((1, N), jnp.float32)] * 4,
         scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)] * 6,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(vmem + 8 * 2**20, 16 * 2**20)),
         interpret=interpret,
+        name="distill_kl_stats",
     )(h_s, w_s, h_t, w_t)
-    return outs                                # lse_s, lse_t, e_t, e_s
+    return [o[0] for o in outs]                # lse_s, lse_t, e_t, e_s
+
+
+def fits(N, V, block_t=256, block_v=512) -> bool:
+    """Whether the kernel takes N rows over a vocabulary of V: each a
+    multiple of its block (or shorter than it)."""
+    return N % min(block_t, N) == 0 and V % min(block_v, V) == 0
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -135,12 +162,23 @@ def distill_kl_pallas(h_student, w_student, h_teacher, w_teacher, *,
                       mask=None, temperature: float = 1.0,
                       interpret: bool = False, block_t: int = 256,
                       block_v: int = 512):
-    N, V = h_student.shape[0], w_student.shape[1]
+    """Raises ``ValueError`` when N or V is not a multiple of its block;
+    a caller that wants the jnp path asks ``kernels.ops`` for
+    ``impl="ref"``.  ``block_v`` is halved (staying a divisor of V) until
+    the VMEM working set fits ``_VMEM_BUDGET``."""
+    N, Ds = h_student.shape
+    Dt = h_teacher.shape[1]
+    V = w_student.shape[1]
     bt = min(block_t, N)
     bv = min(block_v, V)
-    if N % bt or V % bv:
-        return _dk.distill_kl_chunked_jnp(
-            h_student, w_student, h_teacher, w_teacher, mask=mask,
-            temperature=temperature, block_v=block_v)
+    if not fits(N, V, block_t, block_v):
+        raise ValueError(
+            f"distill_kl_pallas: N={N}, V={V} are not multiples of the "
+            f"blocks block_t={bt}, block_v={bv}; pad them or use "
+            f"impl='ref'")
+    itemsize = jnp.dtype(w_student.dtype).itemsize
+    while (_vmem_bytes(bt, bv, Ds, Dt, itemsize) > _VMEM_BUDGET
+           and bv % 256 == 0):
+        bv //= 2
     return _distill_kl_p(h_student, w_student, h_teacher, w_teacher, mask,
                          float(temperature), bt, bv, bool(interpret))

@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
+from repro.kernels.lanes import col_to_row as _col_to_row
 
 NEG_INF = -1e30
 
@@ -93,21 +94,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, window,
         if window > 0:
             mask = jnp.logical_and(mask, q_pos - kv_pos < window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+        m_ref[...] = m_new
 
     @pl.when(ik == nkv - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(l)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = _col_to_row(m_ref[...] + jnp.log(l))
 
 
 def _flash_fwd_pallas(q, k, v, kv_pos, *, scale, causal, window, block_q,
@@ -138,9 +139,11 @@ def _flash_fwd_pallas(q, k, v, kv_pos, *, scale, causal, window, block_q,
         in_specs.append(
             pl.BlockSpec((1, bkv), lambda b, h, iq, ik: (0, ik)))
         args.append(kv_pos.reshape(1, T).astype(jnp.int32))
+    # lse is written lane-dense as [B, H, 1, S] rows: a (1, bq) block has
+    # the full array's second-minor dim, which Mosaic tiles natively
     out_shape = [
         jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        jax.ShapeDtypeStruct((B, H, S), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         kernel,
@@ -148,7 +151,7 @@ def _flash_fwd_pallas(q, k, v, kv_pos, *, scale, causal, window, block_q,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
         ],
         out_shape=out_shape,
         scratch_shapes=[
@@ -156,9 +159,13 @@ def _flash_fwd_pallas(q, k, v, kv_pos, *, scale, causal, window, block_q,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
-    return o, lse
+    return o, lse[:, :, 0]
 
 
 def _zgrad(x):
@@ -251,34 +258,47 @@ def _positions(kv_positions, q_offset, T):
     return kv_positions - jnp.asarray(q_offset, jnp.int32)
 
 
+def fits(S, T, block_q=512, block_kv=512, segmented=False) -> bool:
+    """Whether the kernel takes S queries and T keys: each a multiple of
+    its block (or shorter than it), and no segment ids."""
+    return (not segmented and S % min(block_q, S) == 0
+            and T % min(block_kv, T) == 0)
+
+
+def _blocks(S, T, block_q, block_kv):
+    """Static (bq, bkv) for the kernel; raises where a length is not a
+    multiple of its block (a caller that wants the jnp path asks
+    ``kernels.ops`` for ``impl="ref"``)."""
+    bq = min(block_q, S)
+    bkv = min(block_kv, T)
+    if not fits(S, T, block_q, block_kv):
+        raise ValueError(
+            f"Pallas flash attention: S={S}, T={T} are not multiples of "
+            f"the blocks block_q={bq}, block_kv={bkv}; pad them or use "
+            f"impl='ref'")
+    return bq, bkv
+
+
 def flash_attention(q, k, v, *, causal=True, window=0, segment_q=None,
                     segment_kv=None, scale: Optional[float] = None,
                     q_offset=0, kv_positions=None, interpret: bool = False,
                     block_q: int = 512, block_kv: int = 512):
     """Pallas flash attention; q [B,S,H,D], k/v [B,T,KV,D].
 
-    Segment ids fall back to the jnp blockwise path (they appear only in
-    packed-sequence contexts where the caller already composes its own
-    kernel); q_offset / kv_positions run natively via the positions
-    operand."""
+    q_offset / kv_positions run natively via the positions operand.  The
+    kernel has no segment-id operand: packed sequences raise here and go
+    through ``impl="ref"``."""
     if segment_q is not None or segment_kv is not None:
-        return _ref.flash_attention_jnp(
-            q, k, v, causal=causal, window=window, segment_q=segment_q,
-            segment_kv=segment_kv, scale=scale, q_offset=q_offset,
-            kv_positions=kv_positions, block_q=block_q, block_kv=block_kv)
+        raise ValueError(
+            "Pallas flash attention has no segment-id path; use "
+            "impl='ref' for packed sequences")
     B, S, H, D = q.shape
     T = k.shape[1]
     scale = scale if scale is not None else D ** -0.5
-    bq = min(block_q, S)
-    bkv = min(block_kv, T)
-    if S % bq or T % bkv:
-        return _ref.flash_attention_jnp(
-            q, k, v, causal=causal, window=window, scale=scale,
-            q_offset=q_offset, kv_positions=kv_positions,
-            block_q=block_q, block_kv=block_kv)
+    blocks = _blocks(S, T, block_q, block_kv)
     kv_pos = _positions(kv_positions, q_offset, T)
     return _flash_pallas(q, k, v, kv_pos, bool(causal), int(window),
-                         float(scale), (bq, bkv), bool(interpret), q.dtype)
+                         float(scale), blocks, bool(interpret), q.dtype)
 
 
 def flash_attention_lse(q, k, v, *, causal=True, window=0,
@@ -293,16 +313,10 @@ def flash_attention_lse(q, k, v, *, causal=True, window=0,
     B, S, H, D = q.shape
     T = k.shape[1]
     scale = scale if scale is not None else D ** -0.5
-    bq = min(block_q, S)
-    bkv = min(block_kv, T)
-    if S % bq or T % bkv:
-        return _ref.flash_attention_jnp_lse(
-            q, k, v, causal=causal, window=window, scale=scale,
-            q_offset=q_offset, kv_positions=kv_positions,
-            block_q=block_q, block_kv=block_kv)
+    blocks = _blocks(S, T, block_q, block_kv)
     kv_pos = _positions(kv_positions, q_offset, T)
     return _flash_pallas_lse(q, k, v, kv_pos, bool(causal), int(window),
-                             float(scale), (bq, bkv), bool(interpret),
+                             float(scale), blocks, bool(interpret),
                              q.dtype)
 
 

@@ -7,6 +7,11 @@ Each op picks an implementation:
 * ``ref``              — the memory-efficient jnp path (``ref.py``).
 * ``auto``             — pallas on TPU, ref elsewhere.
 
+Where the kernel cannot take a call's shapes (a length that is not a
+multiple of its block, or segment ids), a Pallas tier that ``auto`` or
+``REPRO_KERNEL_IMPL`` picked runs ``ref`` instead and warns once per shape;
+a Pallas tier the caller named raises ``ValueError`` in the kernel.
+
 The model stack always calls through here, so swapping in the TPU kernel is a
 config change, not a code change.  A ``REPRO_KERNEL_IMPL`` environment
 variable overrides every dispatch repo-wide (CI forces
@@ -15,6 +20,7 @@ variable overrides every dispatch repo-wide (CI forces
 from __future__ import annotations
 
 import os
+import warnings
 from typing import Optional
 
 import jax
@@ -34,7 +40,14 @@ def _on_tpu() -> bool:
         return False
 
 
-def _resolve(impl: str) -> str:
+_PALLAS = ("pallas", "pallas_interpret")
+_REF_WARNED: set = set()
+
+
+def resolve(impl: str, *, fits: bool = True, what: str = "") -> str:
+    """The tier a call runs.  ``fits`` says whether the Pallas kernel
+    takes the call's shapes (``what`` names them in the warning)."""
+    asked = impl
     env = os.environ.get("REPRO_KERNEL_IMPL", "")
     if env:
         if env not in _IMPLS:
@@ -42,10 +55,25 @@ def _resolve(impl: str) -> str:
                 f"REPRO_KERNEL_IMPL={env!r}: expected one of {_IMPLS}")
         impl = env
     if impl == "auto":
-        return "pallas" if _on_tpu() else "ref"
+        impl = "pallas" if _on_tpu() else "ref"
     if impl not in _IMPLS:
         raise ValueError(f"unknown kernel impl {impl!r}")
+    if impl in _PALLAS and not fits and asked not in _PALLAS:
+        if what not in _REF_WARNED:
+            _REF_WARNED.add(what)
+            warnings.warn(f"{what}: the Pallas kernel cannot take these "
+                          "shapes; running impl='ref'", stacklevel=3)
+        return "ref"
     return impl
+
+
+def flash_impl(impl: str, S: int, T: int, *, segmented: bool = False,
+               block_q: int = 512, block_kv: int = 512) -> str:
+    """:func:`resolve` for flash attention over S queries and T keys."""
+    from repro.kernels import flash_attention as fa
+    return resolve(impl, fits=fa.fits(S, T, block_q, block_kv, segmented),
+                   what=f"flash attention S={S} T={T}"
+                   + (" with segment ids" if segmented else ""))
 
 
 # --------------------------------------------------------------------------- #
@@ -54,8 +82,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: Optional[float] = None, q_offset=0,
                     kv_positions=None, impl: str = "auto",
                     block_q: int = 512, block_kv: int = 512):
-    impl = _resolve(impl)
-    if impl in ("pallas", "pallas_interpret"):
+    impl = flash_impl(impl, q.shape[1], k.shape[1],
+                      segmented=segment_q is not None
+                      or segment_kv is not None,
+                      block_q=block_q, block_kv=block_kv)
+    if impl in _PALLAS:
         from repro.kernels import flash_attention as fa
         return fa.flash_attention(
             q, k, v, causal=causal, window=window,
@@ -87,8 +118,9 @@ def flash_attention_lse(q, k, v, *, causal: bool = True, window: int = 0,
     The chunked CP path calls this per KV chunk and merges the partials
     with ``flash_attention.merge_flash_partials``; no ``ref_naive`` tier
     (the naive oracle has no lse output)."""
-    impl = _resolve(impl)
-    if impl in ("pallas", "pallas_interpret"):
+    impl = flash_impl(impl, q.shape[1], k.shape[1], block_q=block_q,
+                      block_kv=block_kv)
+    if impl in _PALLAS:
         from repro.kernels import flash_attention as fa
         return fa.flash_attention_lse(
             q, k, v, causal=causal, window=window, scale=scale,
@@ -110,8 +142,11 @@ def distill_kl(h_student, w_student, h_teacher, w_teacher, *, mask=None,
     """Chunked-vocab KL(p_t || p_s) from hidden states (never materializes
     the [N, V] teacher logits — the kernel form of Maestro's §3.1 colocation
     insight)."""
-    impl = _resolve(impl)
-    if impl in ("pallas", "pallas_interpret"):
+    from repro.kernels import distill_kl_pallas as dkp
+    N, V = h_student.shape[0], w_student.shape[1]
+    impl = resolve(impl, fits=dkp.fits(N, V, block_v=block_v),
+                   what=f"distill_kl N={N} V={V}")
+    if impl in _PALLAS:
         from repro.kernels import distill_kl as dk
         return dk.distill_kl(h_student, w_student, h_teacher, w_teacher,
                              mask=mask, temperature=temperature,
@@ -132,8 +167,8 @@ def distill_kl(h_student, w_student, h_teacher, w_teacher, *, mask=None,
 # --------------------------------------------------------------------------- #
 def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, impl: str = "auto"):
     """Mamba2 SSD over a full sequence. See ref.ssd_reference for shapes."""
-    impl = _resolve(impl)
-    if impl in ("pallas", "pallas_interpret"):
+    impl = resolve(impl)
+    if impl in _PALLAS:
         from repro.kernels import ssd_scan as ssd
         return ssd.ssd_scan(x, dt, A, B, C, D, chunk=chunk,
                             interpret=(impl == "pallas_interpret"))

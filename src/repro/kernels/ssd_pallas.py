@@ -5,13 +5,16 @@ the inter-chunk SSM state [headdim, d_state] persists in VMEM scratch
 across chunk steps — the recurrence never round-trips HBM, which is the
 TPU-native replacement for the paper's warp-level chunk scan.
 
-Per chunk (Q = chunk length), everything is MXU-shaped:
+The jit wrapper folds A into the per-chunk cumulative log-decay
+``cum = cumsum(A·dt)`` (laid out as lane-dense [1, Q] rows) and the input
+into ``dx = dt⊙x``, so the kernel needs no per-head scalars.  Per chunk
+(Q = chunk length), everything is MXU-shaped:
   cb     = C·Bᵀ                      [Q, Q]
   scores = cb ⊙ tril(exp(cum_i−cum_j))
-  y      = scores·(dt⊙x) + exp(cum)·(C·stateᵀ)
-  state  = exp(cum_Q)·state + (decay_end⊙dt⊙x)ᵀ·B
+  y      = scores·dx + exp(cum)·(C·stateᵀ)
+  state  = exp(cum_Q)·state + (decay_end⊙dx)ᵀ·B
 
-The D·x skip and dt softplus/bias run in the jit wrapper (fused by XLA).
+The D·x skip runs in the jit wrapper (fused by XLA).
 Backward: custom VJP that recomputes through the chunked-jnp
 implementation (same math, memory-bounded).
 """
@@ -25,42 +28,40 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ssd_scan as _ssd
+from repro.kernels.lanes import row_to_col as _row_to_col
 
 
-def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, state_ref, *, nc):
+def _kernel(dx_ref, cum_ref, B_ref, C_ref, y_ref, state_ref):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, 0, 0].astype(jnp.float32)          # [Q, P]
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)        # [Q]
-    A = A_ref[0]                                    # scalar
+    dx = dx_ref[0, 0, 0]                            # [Q, P]  dt·x
+    cum_row = cum_ref[0, 0, 0]                      # [1, Q]  log-decays
+    cum = _row_to_col(cum_row)                      # [Q, 1]
     Bm = B_ref[0, 0].astype(jnp.float32)            # [Q, N]
     Cm = C_ref[0, 0].astype(jnp.float32)            # [Q, N]
-    Q = x.shape[0]
+    Q = dx.shape[0]
 
-    a = A * dt                                      # [Q] log-decays
-    cum = jnp.cumsum(a)                             # [Q]
     iq = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(iq >= jq, jnp.exp(cum[:, None] - cum[None, :]), 0.0)
+    L = jnp.where(iq >= jq, jnp.exp(cum - cum_row), 0.0)
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     scores = cb * L                                 # [Q, Q]
-    dx = dt[:, None] * x                            # [Q, P]
     y_intra = jax.lax.dot_general(scores, dx, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     state = state_ref[...]                          # [P, N]
     y_inter = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y_inter = y_inter * jnp.exp(cum)[:, None]       # [Q, P]
+    y_inter = y_inter * jnp.exp(cum)                # [Q, P]
     y_ref[0, 0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    decay_end = jnp.exp(cum[-1] - cum)              # [Q]
-    wx = (decay_end * dt)[:, None] * x              # [Q, P]
-    new_state = state * jnp.exp(cum[-1]) + jax.lax.dot_general(
+    cum_end = cum_row[:, Q - 1:]                    # [1, 1]
+    wx = jnp.exp(cum_end - cum) * dx                # [Q, P]
+    new_state = state * jnp.exp(cum_end) + jax.lax.dot_general(
         wx, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)         # [P, N]
     state_ref[...] = new_state
@@ -75,19 +76,22 @@ def _ssd_fwd_pallas(x, dt, A, B, C, *, chunk, interpret):
     while s % Q:
         Q //= 2
     nc = s // Q
-    xr = x.transpose(0, 2, 1, 3).reshape(b, h, nc, Q, p)
-    dtr = dt.transpose(0, 2, 1).reshape(b, h, nc, Q)
+    dtf = dt.astype(jnp.float32)
+    dx = (dtf[..., None] * x.astype(jnp.float32))
+    dx = dx.transpose(0, 2, 1, 3).reshape(b, h, nc, Q, p)
+    a = (dtf * A.astype(jnp.float32)[None, None, :]).transpose(0, 2, 1)
+    cum = jnp.cumsum(a.reshape(b, h, nc, Q), axis=-1).reshape(
+        b, h, nc, 1, Q)
     Br = B.reshape(b, nc, Q, n)
     Cr = C.reshape(b, nc, Q, n)
-    kernel = functools.partial(_kernel, nc=nc)
     y = pl.pallas_call(
-        kernel,
+        _kernel,
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, p), lambda ib, ih, ic: (ib, ih, ic,
                                                               0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda ib, ih, ic: (ib, ih, ic, 0)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
+            pl.BlockSpec((1, 1, 1, 1, Q), lambda ib, ih, ic: (ib, ih, ic,
+                                                              0, 0)),
             pl.BlockSpec((1, 1, Q, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
             pl.BlockSpec((1, 1, Q, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
         ],
@@ -95,8 +99,11 @@ def _ssd_fwd_pallas(x, dt, A, B, C, *, chunk, interpret):
                                lambda ib, ih, ic: (ib, ih, ic, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, nc, Q, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xr, dtr, A.astype(jnp.float32), Br, Cr)
+        name="ssd_scan_fwd",
+    )(dx, cum, Br, Cr)
     return y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
 
 

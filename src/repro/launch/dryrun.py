@@ -3,6 +3,7 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count="
     + os.environ.get("REPRO_DRYRUN_DEVICES", "512")
     + (" " + os.environ["XLA_FLAGS"] if "XLA_FLAGS" in os.environ else ""))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

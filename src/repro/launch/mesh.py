@@ -1,4 +1,4 @@
-"""Production mesh builders.
+"""The mesh constructor and the production meshes built with it.
 
 Functions (not module-level constants) so importing this module never
 touches jax device state.
@@ -6,6 +6,18 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """The one mesh constructor.  Every axis is ``Auto``: the step
+    builders place arrays with ``NamedSharding`` and let jit propagate
+    the rest, which ``jax.make_mesh``'s default ``Explicit`` axes refuse
+    (an embedding gather then raises ``ShardingTypeError``)."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, pp: int = 1,
@@ -17,18 +29,14 @@ def make_production_mesh(*, multi_pod: bool = False, pp: int = 1,
     if pp == 1 and cp == 1:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     assert 16 % (pp * cp) == 0, (pp, cp)
     shape = (16 // (pp * cp), pp, cp, 16)
     axes = ("data", "pipe", "seq", "model")
     if multi_pod:
         shape = (2,) + shape
         axes = ("pod",) + axes
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def mesh_from_env(var: str = "REPRO_DRYRUN_MESH"):
@@ -52,4 +60,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (virtual) devices this host exposes."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

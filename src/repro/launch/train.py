@@ -24,6 +24,8 @@ from repro.models.model import build_model
 from repro.optim import adamw, schedules
 from repro.train import step as step_mod
 from repro.train.loop import train
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 PRESETS = {
     "lm-100m": ArchConfig(name="lm-100m", family="dense", num_layers=12,
@@ -62,7 +64,8 @@ def main() -> None:
         cfg = get_config(args.arch)
     cfg = cfg.replace(dtype=args.dtype)
 
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    print(f"compile cache: {enable_compile_cache() or 'off'}")
+    mesh = make_mesh((args.data, args.model), ("data", "model"))
     shape = ShapeConfig("cli", "train", args.seq, args.batch)
     mbs = args.mbs or max(args.batch // args.data, 1)
     parallel = ParallelConfig(dp=args.data, tp=args.model, mbs=mbs)

@@ -55,7 +55,7 @@ LM_KEYS = ("tokens", "labels", "loss_mask", "image_pos", "image_valid")
 # Shared per-microbatch arithmetic (oracle ≡ disaggregated, bit-for-bit)
 # --------------------------------------------------------------------------- #
 def vit_forward(pv, vit_cfg: ArchConfig, patches, valid, *,
-                impl: str = "ref", remat: bool = True):
+                impl: str = "auto", remat: bool = True):
     """ViT-encode the gathered image samples of one microbatch and mask
     padding rows.  patches [cap, P, pd], valid [cap] → emb [cap, K, Vd]."""
     emb = vlm.vit_encode(pv, vit_cfg, patches, impl=impl, remat=remat)
@@ -121,13 +121,14 @@ def colocated_batch(batch: dict, plan: IterationPlan) -> dict:
 # --------------------------------------------------------------------------- #
 def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
                          mesh: Mesh, *, mbs: int, seq_len: int,
-                         impl: str = "ref", lr_schedule=None,
+                         impl: str = "auto", lr_schedule=None,
                          opt_cfg: adamw.AdamWConfig = adamw.AdamWConfig(),
                          return_grads: bool = False):
     """One jit over the pre-microbatched batch from
     :func:`colocated_batch`: per microbatch, ViT fwd on the gathered image
     samples + LM loss, per-microbatch joint grads accumulated in dispatch
-    order, one AdamW update.  Returns (step, shardings)."""
+    order, one AdamW update.  ``params`` and ``opt_state`` are donated.
+    Returns (step, shardings)."""
     model = build_model(lm_cfg, impl=impl)
     v_specs = vlm.vit_specs(vit_cfg)
     l_specs = model.specs()
@@ -155,7 +156,7 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
                                              peak_lr=1e-3)
 
     def joint_loss(ps, mb, vidx, vval):
-        with cm.act_hook(hook):
+        with cm.act_hook(*hook):
             sub = mb["patches"][vidx]
             emb = vit_forward(ps["vit"], vit_cfg, sub, vval, impl=impl)
             return lm_microbatch_loss(ps["lm"], model, mb, emb, vidx)
@@ -193,19 +194,26 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
     out_metrics = {"loss": rep, "grad_norm": rep, "lr": rep}
     if return_grads:
         out_metrics["grads"] = p_shard
+    # params and optimizer state are donated: at published widths two
+    # copies of them do not fit one chip's HBM
     jitted = jax.jit(step,
                      in_shardings=(p_shard, o_shard, b_shard, rep),
-                     out_shardings=(p_shard, o_shard, out_metrics))
+                     out_shardings=(p_shard, o_shard, out_metrics),
+                     donate_argnums=(0, 1))
     return jitted, {"params": p_shard, "opt": o_shard, "batch": b_shard}
 
 
-def init_compound_params(vit_cfg: ArchConfig, lm_cfg: ArchConfig, rng):
-    """Joint {vit, lm} params on the default device (place with
-    ``jax.device_put`` onto either the oracle mesh or section meshes)."""
+def init_compound_params(vit_cfg: ArchConfig, lm_cfg: ArchConfig, rng,
+                         shardings=None):
+    """Joint {vit, lm} params, drawn into ``shardings`` (``{"vit", "lm"}``
+    sharding trees: the oracle's or the section meshes') or, without it,
+    onto the default device.  The values do not depend on the
+    placement."""
     model = build_model(lm_cfg)
     r_v, r_l = jax.random.split(rng)
-    return {"vit": cm.init_params(vlm.vit_specs(vit_cfg), r_v),
-            "lm": cm.init_params(model.specs(), r_l)}
+    sh = shardings or {"vit": None, "lm": None}
+    return {"vit": cm.init_params(vlm.vit_specs(vit_cfg), r_v, sh["vit"]),
+            "lm": cm.init_params(model.specs(), r_l, sh["lm"])}
 
 
 # --------------------------------------------------------------------------- #
@@ -214,7 +222,7 @@ def init_compound_params(vit_cfg: ArchConfig, lm_cfg: ArchConfig, rng):
 def mllm_spec(vit_cfg: ArchConfig, lm_cfg: ArchConfig, *,
               vit_parallel: ParallelConfig, lm_parallel: ParallelConfig,
               global_batch: int, seq_len: int, mbs: int,
-              impl: str = "ref") -> wl.WorkloadSpec:
+              impl: str = "auto") -> wl.WorkloadSpec:
     """The MLLM workload as a declaration: a data-dependent ViT section
     emitting per-microbatch vision embeddings, and the critical LM
     section scattering them into image slots.  Everything else — carved
@@ -267,7 +275,7 @@ class MLLMRuntime:
     def __init__(self, vit_cfg: ArchConfig, lm_cfg: ArchConfig, *,
                  vit_parallel: ParallelConfig, lm_parallel: ParallelConfig,
                  global_batch: int, seq_len: int, mbs: int,
-                 devices=None, impl: str = "ref", lr_schedule=None,
+                 devices=None, impl: str = "auto", lr_schedule=None,
                  opt_cfg: adamw.AdamWConfig = adamw.AdamWConfig(),
                  lookahead: int = 0):
         assert global_batch % mbs == 0, (global_batch, mbs)
@@ -295,7 +303,9 @@ class MLLMRuntime:
 
     # ------------------------------------------------------------------ #
     def init(self, rng):
-        params = init_compound_params(self.vit_cfg, self.lm_cfg, rng)
+        sh = self._crt._p_shard
+        params = init_compound_params(self.vit_cfg, self.lm_cfg, rng,
+                                      {"vit": sh["vit"], "lm": sh["llm"]})
         return self.place(params)
 
     def place(self, params):

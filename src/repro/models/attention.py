@@ -129,9 +129,15 @@ def attention(p, x, cfg: ArchConfig, *, causal: bool = True,
                        segment_q=segment_ids, segment_kv=segment_ids,
                        scale=None)
     else:
-        o = kops.flash_attention(
-            q, k, v, causal=causal, window=cfg.sliding_window,
-            segment_q=segment_ids, segment_kv=segment_ids, impl=impl)
+        def kernel(q, k, v):
+            return kops.flash_attention(
+                q, k, v, causal=causal, window=cfg.sliding_window,
+                segment_q=segment_ids, segment_kv=segment_ids, impl=impl)
+        tier = kops.flash_impl(impl, q.shape[1], k.shape[1],
+                               segmented=segment_ids is not None)
+        o = (cm.per_shard(kernel, q, k, v) if tier in ("pallas",
+                                                       "pallas_interpret")
+             else kernel(q, k, v))
     o = _unpad_o_heads(cm.shard_act(o, "attn_q"), cfg, H)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
 

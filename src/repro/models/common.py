@@ -11,6 +11,7 @@ axes, init).  From that single source of truth we derive
 from __future__ import annotations
 
 import math
+import warnings
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -60,12 +61,23 @@ def _init_one(spec: ParamSpec, key) -> jnp.ndarray:
             * std).astype(spec.dtype)
 
 
-def init_params(spec_tree, key):
-    leaves, treedef = jax.tree_util.tree_flatten(spec_tree,
-                                                 is_leaf=_leaf_is_spec)
+def _draw(leaves, treedef, key):
     keys = jax.random.split(key, len(leaves))
     vals = [_init_one(s, k) for s, k in zip(leaves, keys)]
     return jax.tree_util.tree_unflatten(treedef, vals)
+
+
+def init_params(spec_tree, key, shardings=None):
+    """Materialize ``spec_tree`` from ``key`` in one jit.
+
+    ``shardings`` (a tree matching ``spec_tree``, or one sharding for every
+    leaf) draws each leaf straight into its placement; without it the tree
+    lands on the default device.  One jit keeps the f32 draws fused, where
+    leaf-by-leaf eager draws leave them pending on the default device."""
+    leaves, treedef = jax.tree_util.tree_flatten(spec_tree,
+                                                 is_leaf=_leaf_is_spec)
+    draw = jax.jit(_draw, static_argnums=(0, 1), out_shardings=shardings)
+    return draw(tuple(leaves), treedef, key)
 
 
 def stack_specs(spec_tree, n: int, axis_name: str = "layers"):
@@ -85,6 +97,15 @@ def stack_specs(spec_tree, n: int, axis_name: str = "layers"):
 import contextlib
 
 _ACT_HOOK = None
+_KERNEL_LAYOUT = None
+
+
+class KernelLayout(NamedTuple):
+    """The mesh a step shards activations over, and the mesh axes of the
+    batch and head dims (None where unsharded)."""
+    mesh: Any
+    batch: Any
+    heads: Optional[str]
 
 
 def shard_act(x, kind: str):
@@ -92,14 +113,55 @@ def shard_act(x, kind: str):
 
 
 @contextlib.contextmanager
-def act_hook(fn):
-    global _ACT_HOOK
-    prev = _ACT_HOOK
-    _ACT_HOOK = fn
+def act_hook(fn, kernel_layout: Optional[KernelLayout] = None):
+    """Install the activation hook ``fn`` and the layout that
+    :func:`per_shard` runs Pallas kernels under."""
+    global _ACT_HOOK, _KERNEL_LAYOUT
+    prev = _ACT_HOOK, _KERNEL_LAYOUT
+    _ACT_HOOK, _KERNEL_LAYOUT = fn, kernel_layout
     try:
         yield
     finally:
-        _ACT_HOOK = prev
+        _ACT_HOOK, _KERNEL_LAYOUT = prev
+
+
+_UNSPLIT_WARNED: set = set()
+
+
+def per_shard(fn: Callable, *xs):
+    """``fn(*xs)`` on [B, S, H, D] operands, run per (batch, head) shard
+    of the installed kernel layout's mesh.
+
+    The TPU compiler cannot partition a Pallas (Mosaic) kernel, so a
+    kernel call in a step jitted over several devices goes through a
+    ``shard_map``.  A dim is split only where every operand divides its
+    mesh axes; otherwise every device runs the whole dim, and a warning
+    names it once.  Without a layout, or on one device, this is
+    ``fn(*xs)``."""
+    layout = _KERNEL_LAYOUT
+    if layout is None or layout.mesh.size == 1:
+        return fn(*xs)
+    sizes = dict(layout.mesh.shape)
+
+    def axis(ax, dim):
+        names = () if ax is None else (ax if isinstance(ax, tuple)
+                                       else (ax,))
+        n = math.prod(sizes[a] for a in names)
+        if all(x.shape[dim] % n == 0 for x in xs):
+            return ax if names else None
+        shapes = tuple(x.shape for x in xs)
+        if (shapes, dim) not in _UNSPLIT_WARNED:
+            _UNSPLIT_WARNED.add((shapes, dim))
+            warnings.warn(f"per_shard: dim {dim} of {shapes} does not "
+                          f"divide mesh axes {names} ({n}); every device "
+                          "runs the kernel over the whole dim",
+                          stacklevel=3)
+        return None
+
+    spec = jax.sharding.PartitionSpec(axis(layout.batch, 0), None,
+                                      axis(layout.heads, 2), None)
+    return jax.shard_map(fn, mesh=layout.mesh, in_specs=(spec,) * len(xs),
+                         out_specs=spec, check_vma=False)(*xs)
 
 
 # --------------------------------------------------------------------------- #

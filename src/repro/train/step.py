@@ -148,7 +148,10 @@ def _act_hook_for(mesh: Mesh, batch_size: int, seq_len: int,
                 x, NamedSharding(mesh, P(b_ax, None, None, None)))
         return x
 
-    return hook
+    # Pallas kernels run per (batch, head) shard; under CP, attention
+    # goes through cp_attention's own shard_map instead
+    return hook, cm.KernelLayout(mesh, b_ax,
+                                 "model" if model_size > 1 else None)
 
 
 def num_microbatches(shape: ShapeConfig, mesh: Mesh,
@@ -284,7 +287,7 @@ def build_train_step(model: Model, mesh: Mesh, parallel: ParallelConfig,
         def loss_fn(p, mb):
             impl_ctx = (att.attention_impl(cp_impl) if cp_impl is not None
                         else contextlib.nullcontext())
-            with cm.act_hook(hook), impl_ctx:
+            with cm.act_hook(*hook), impl_ctx:
                 return model.loss(p, mb)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -443,7 +446,7 @@ def build_prefill_step(model: Model, mesh: Mesh, shape: ShapeConfig, *,
                                        model.cfg.padded_vocab)
 
     def prefill_step(params, batch):
-        with cm.act_hook(hook):
+        with cm.act_hook(*hook):
             logits, cache = model.prefill(params, batch)
         return logits, cache
 

@@ -4,6 +4,7 @@ steps, and the full compressed training paths (build_train_step and
 CompoundRuntime) tracking the uncompressed loss trajectory."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp
@@ -19,9 +20,10 @@ from repro.models.model import build_model
 from repro.optim import adamw, compression as gcomp
 from repro.roofline import analysis as ra
 from repro.train import step as step_mod
+from repro.launch.mesh import make_mesh
 
 DP, N = 8, 5000
-mesh8 = jax.make_mesh((DP,), ("data",))
+mesh8 = make_mesh((DP,), ("data",))
 rng = np.random.default_rng(0)
 
 

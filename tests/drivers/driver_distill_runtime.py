@@ -3,6 +3,7 @@ teacher and student sections on disjoint meshes; and numerical equivalence
 against a monolithic (single-jit) formulation of the same loss."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp

@@ -3,6 +3,7 @@ mesh shape; (b) tiny dry-run cells (reduced configs, 8-device meshes) for a
 train, a decode, and a MoE cell — exercising the exact dryrun code path."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["REPRO_DRYRUN_TINY"] = "1"
 os.environ["REPRO_DRYRUN_DEVICES"] = "8"
 
@@ -15,10 +16,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.launch.mesh import make_mesh
 
 # ---- elastic restore ------------------------------------------------------
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))
 x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 xa = jax.device_put(x, NamedSharding(mesh_a, P("data", "model")))
 with tempfile.TemporaryDirectory() as d:

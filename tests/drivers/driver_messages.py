@@ -1,6 +1,7 @@
 """Multi-device driver: M-to-N message queue resharding across meshes."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp

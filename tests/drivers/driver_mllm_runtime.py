@@ -6,6 +6,7 @@ single-jit oracle on mixed image/text batches AND on an all-text batch
 where the vision section never fires."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +40,13 @@ assert not (set(rt.rt.mesh("vit").devices.flat)
             & set(rt.rt.mesh("llm").devices.flat)), "meshes must be disjoint"
 
 params_host = init_compound_params(vit_cfg, lm_cfg, jax.random.PRNGKey(0))
+
+
+def fresh_params():
+    """A copy of params_host for the oracle, whose step donates its
+    inputs (a replicated device_put can alias the source buffers)."""
+    return jax.tree_util.tree_map(jnp.copy, params_host)
+
 params, opts = rt.place(params_host)
 
 # colocated single-jit oracle on a 4-device dp=4 mesh (same section layout)
@@ -46,7 +54,7 @@ omesh = section_mesh(jax.devices()[:4], ParallelConfig(dp=4), "oracle")
 ostep, oshard = build_colocated_step(vit_cfg, lm_cfg, omesh, mbs=MBS,
                                      seq_len=S, impl="ref",
                                      opt_cfg=opt_cfg, return_grads=True)
-oparams = jax.device_put(params_host, oshard["params"])
+oparams = jax.device_put(fresh_params(), oshard["params"])
 oopt = jax.device_put(adamw.init(oparams), oshard["opt"])
 
 data = vlm_batches(batch=B, seq_len=S, vocab=256, vision_ratio=0.5,
@@ -146,7 +154,7 @@ params_c, opts_c = rt2.place(params_host)
 ostep2, oshard2 = build_colocated_step(vit_cfg, lm_cfg, omesh, mbs=MBS,
                                        seq_len=S, impl="ref",
                                        opt_cfg=clip_cfg, return_grads=True)
-oparams_c = jax.device_put(params_host, oshard2["params"])
+oparams_c = jax.device_put(fresh_params(), oshard2["params"])
 oopt_c = jax.device_put(adamw.init(oparams_c), oshard2["opt"])
 cbatch = next(data)
 cplan = rt2.plan_iteration(np.asarray(cbatch["has_image"]), reorder=True)
@@ -192,7 +200,7 @@ rt3 = MLLMRuntime(vit_cfg, lm_cfg,
 vm3 = rt3.rt.mesh("vit")
 assert dict(vm3.shape)["seq"] == 2 and dict(vm3.shape)["data"] == 2
 params_cp, opts_cp = rt3.place(params_host)
-oparams3 = jax.device_put(params_host, oshard["params"])
+oparams3 = jax.device_put(fresh_params(), oshard["params"])
 oopt3 = jax.device_put(adamw.init(oparams3), oshard["opt"])
 cpbatch = next(data)
 cp_plan = rt3.plan_iteration(np.asarray(cpbatch["has_image"]),
@@ -235,7 +243,7 @@ rt4 = MLLMRuntime(vit_cfg, lm_cfg,
                   impl="ref", opt_cfg=opt_cfg, lookahead=1)
 params_s, opts_s = rt4.place(params_host)
 rt4.install(params_s, opts_s)
-op4 = jax.device_put(params_host, oshard["params"])
+op4 = jax.device_put(fresh_params(), oshard["params"])
 oo4 = jax.device_put(adamw.init(op4), oshard["opt"])
 sbatches = [next(data) for _ in range(3)]
 splans = []

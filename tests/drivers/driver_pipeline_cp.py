@@ -6,6 +6,7 @@ backward against the naive reference, plus the kernel-substrate dispatch
 whole CP matrix through the interpreted Pallas kernel)."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import sys
 
@@ -19,6 +20,7 @@ from repro.dist.pipeline import build_pp_loss
 from repro.kernels import ref
 from repro.models import transformer as tf
 from repro.models.common import init_params
+from repro.launch.mesh import make_mesh
 
 CP_ONLY = "--cp-only" in sys.argv[1:]
 
@@ -26,7 +28,7 @@ CP_ONLY = "--cp-only" in sys.argv[1:]
 if not CP_ONLY:
     cfg = get_reduced("granite-3-8b").replace(dtype="float32",
                                               num_layers=4)
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     loss_fn, _ = build_pp_loss(cfg, mesh, n_micro=2)
     params = init_params(tf.lm_specs(cfg), jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -84,7 +86,7 @@ def check(mesh, mode, chunks=1, window=0, tol=2e-5):
 # cp=4 divides both H=8 and KV=4: ulysses territory, monolithic and
 # overlap-chunked (the a2a interleaving makes chunk positions strided —
 # kv_positions keeps causal/window masking exact).
-mesh4 = jax.make_mesh((4,), ("model",))
+mesh4 = make_mesh((4,), ("model",))
 check(mesh4, "ulysses")
 check(mesh4, "allgather")
 for chunks in (2, 4):
@@ -93,7 +95,7 @@ check(mesh4, "ulysses", chunks=4, window=24)
 
 # cp=8 does not divide KV=4: head-replicated ulysses vs the allgather
 # fallback (the comm claim lives in the ulysses_mqa gate; exactness here).
-mesh8 = jax.make_mesh((8,), ("model",))
+mesh8 = make_mesh((8,), ("model",))
 check(mesh8, "ulysses_mqa")
 check(mesh8, "allgather")
 check(mesh8, "auto")        # resolves to ulysses_mqa at this shape

@@ -11,6 +11,7 @@ Verifies on an 8-device host-platform mesh that
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import functools
 
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.launch.mesh import make_mesh
 from repro.core.types import ParallelConfig, ShapeConfig
 from repro.dist import sharding as shd
 from repro.dist.pipeline import build_pp_loss
@@ -97,7 +99,7 @@ from repro.distill.workload import build_colocated_step
 
 
 def run_distill(parallel, mesh_dims, axes):
-    mesh = jax.make_mesh(mesh_dims, axes)
+    mesh = make_mesh(mesh_dims, axes)
     shape = ShapeConfig("d", "train", S, GB)
     step, sh = build_colocated_step(
         cfg, cfg, mesh, shape, parallel, impl="ref", lr_schedule=LR,
@@ -127,7 +129,7 @@ mcfg = get_reduced("mixtral-8x22b").replace(dtype="float32", num_layers=2)
 mparams = init_params(tf.lm_specs(mcfg), jax.random.PRNGKey(1))
 mbatch = make_batch(mcfg, seed=1)
 l_ref, _ = tf.lm_loss(mparams, mcfg, mbatch, impl="ref")
-mesh = jax.make_mesh((2, 2), ("data", "pipe"))
+mesh = make_mesh((2, 2), ("data", "pipe"))
 loss_fn, info = build_pp_loss(mcfg, mesh, n_micro=2, impl="ref")
 assert info["moe_layers_per_stage"] == 1, info
 with mesh:
@@ -168,7 +170,7 @@ def pp_grad_hlo(mesh, vocab_parallel):
             lambda p: loss_fn(p, hb))).lower(hp).compile().as_text()
 
 
-hmesh = jax.make_mesh((2, 4), ("data", "pipe"))
+hmesh = make_mesh((2, 4), ("data", "pipe"))
 rep, m = hlo_gates.evaluate_file(
     hlo_gates.GATES_DIR / "vp_ce.json",
     {"masked": pp_grad_hlo(hmesh, vocab_parallel=False),
@@ -178,8 +180,8 @@ print(f"vp-CE unembed dot FLOPs: masked {m['baseline_full_vocab']:.3g} "
       f"vp {m['shard_present']:.3g} ratio {m['reduction']:.2f} (pp=4)")
 rep.raise_on_error(AssertionError)
 
-tmesh1 = jax.make_mesh((2, 2, 1), ("data", "pipe", "model"))
-tmesh2 = jax.make_mesh((1, 2, 2), ("data", "pipe", "model"))
+tmesh1 = make_mesh((2, 2, 1), ("data", "pipe", "model"))
+tmesh2 = make_mesh((1, 2, 2), ("data", "pipe", "model"))
 rep, m = hlo_gates.evaluate_file(
     hlo_gates.GATES_DIR / "tp_in_stage.json",
     {"tp1": pp_grad_hlo(tmesh1, vocab_parallel=True),
@@ -190,7 +192,7 @@ print(f"TP-in-stage FFN dot FLOPs: tp1 {m['tp1_ffn_present']:.3g} "
 rep.raise_on_error(AssertionError)
 
 # ---- multi-pod PP: the pod axis must carry data parallelism --------------
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "pipe"))
+mesh3 = make_mesh((2, 2, 2), ("pod", "data", "pipe"))
 loss3, info3 = build_pp_loss(cfg, mesh3, n_micro=2, impl="ref")
 assert info3["data_axis"] == ("pod", "data"), info3
 l_base, _ = tf.lm_loss(params, cfg, batch, impl="ref")

@@ -9,10 +9,11 @@ import pytest
 
 from repro.dist import sharding as shd
 from repro.optim import adamw, compression as gcomp
+from repro.launch.mesh import make_mesh
 
 
 def _one_device_mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def _run_axis(fn, *args):

@@ -45,6 +45,19 @@ def test_pallas_matches_oracle(block_t, block_v):
                                rtol=1e-5)
 
 
+@pytest.mark.parametrize("N,V,block_v", [
+    (64, 96, 64),          # vocabulary not a multiple of the block
+    (16, 49155, 2048),     # Granite-3-8B's vocabulary at ops' default block
+])
+def test_pallas_raises_on_misaligned_vocab(N, V, block_v):
+    """A Pallas tier that was asked for never hands the call to jnp."""
+    from repro.kernels import ops
+    hs, ws, ht, wt = _inputs(N, 8, 8, V)
+    with pytest.raises(ValueError, match="impl='ref'"):
+        ops.distill_kl(hs, ws, ht, wt, impl="pallas_interpret",
+                       block_v=block_v)
+
+
 def test_analytic_backward_matches_autodiff():
     hs, ws, ht, wt = _inputs(32, 16, 24, 128)
     mask = jnp.arange(32) % 3 != 0

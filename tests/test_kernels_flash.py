@@ -95,6 +95,25 @@ def test_non_divisible_lengths_pad_path():
                                    atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("case", ["seq_not_multiple", "segment_ids",
+                                  "lse_seq_not_multiple"])
+def test_pallas_raises_where_it_cannot_run(case):
+    """A Pallas tier that was asked for never hands the call to jnp."""
+    from repro.kernels import ops
+    q, k, v = _qkv((1, 96, 96, 4, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="impl='ref'"):
+        if case == "seq_not_multiple":
+            ops.flash_attention(q, k, v, impl="pallas_interpret",
+                                block_q=64, block_kv=64)
+        elif case == "segment_ids":
+            seg = jnp.zeros((1, 96), jnp.int32)
+            ops.flash_attention(q, k, v, segment_q=seg, segment_kv=seg,
+                                impl="pallas_interpret")
+        else:
+            ops.flash_attention_lse(q, k, v, impl="pallas_interpret",
+                                    block_q=64, block_kv=64)
+
+
 def test_q_offset_decode_semantics():
     """q_offset shifts the causal mask (CP shards / decode windows)."""
     B, S, H, KV, D = 1, 64, 2, 2, 8

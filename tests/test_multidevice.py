@@ -44,3 +44,7 @@ def test_elastic_restore_and_tiny_dryrun():
 
 def test_gradient_compression_8way():
     _run("driver_compression.py")
+
+
+def test_chip_smoke_four_chip_path():
+    _run("driver_chip_smoke.py")

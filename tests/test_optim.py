@@ -12,6 +12,7 @@ except ImportError:     # tier-1 must collect without hypothesis installed
 
 from repro.optim import adamw
 from repro.optim.compression import _dequant_int8, _quant_int8, ef_init, wire_bytes
+from repro.launch.mesh import make_mesh
 
 
 def test_adamw_converges_on_quadratic():
@@ -72,7 +73,7 @@ else:
 def test_error_feedback_accumulates_residual():
     from repro.optim.compression import ef_compress_tree
     # single device: psum over a trivial axis via shard_map on 1 device
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     g = {"w": jnp.asarray([0.001, -0.002, 0.003], jnp.float32)}
     ef = ef_init(g)
 

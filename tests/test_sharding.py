@@ -7,8 +7,6 @@ from repro.configs import get_config
 from repro.dist import sharding as shd
 from repro.models.common import ParamSpec
 
-# shd.abstract_mesh: AbstractMesh's constructor signature differs across
-# jax releases; the helper normalizes it.
 MESH = shd.abstract_mesh((16, 16), ("data", "model"))
 MESH3 = shd.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
 

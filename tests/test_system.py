@@ -14,12 +14,13 @@ from repro.models.model import build_model
 from repro.optim import adamw, schedules
 from repro.train import step as step_mod
 from repro.train.loop import train
+from repro.launch.mesh import make_mesh
 
 
 def test_training_learns_on_single_device_mesh(tmp_path):
     cfg = cfgs.get_reduced("qwen1.5-0.5b").replace(
         dtype="float32", num_layers=2, vocab_size=64, d_ff=128)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("tiny", "train", 32, 8)
     model = build_model(cfg)
     step, shardings = step_mod.build_train_step(
@@ -67,7 +68,7 @@ def test_train_step_sharded_single_device_matches_plain():
     """The jitted/sharded step computes the same loss as a plain grad."""
     from conftest import toy_batch
     cfg = cfgs.get_reduced("granite-3-8b").replace(dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("tiny", "train", 16, 4)
     model = build_model(cfg)
     step, shardings = step_mod.build_train_step(

@@ -23,6 +23,7 @@ from repro.dist import sharding as shd
 from repro.models import attention as att
 from repro.models.model import build_model
 from repro.train import step as step_mod
+from repro.launch.mesh import make_mesh
 
 
 def _tiny_model():
@@ -35,7 +36,7 @@ def test_pp_config_on_flat_mesh_raises():
     """The headline bug: pp>1 on a mesh without a pipe axis used to train
     silently replicated."""
     model = _tiny_model()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="pp"):
         step_mod.build_train_step(model, mesh, ParallelConfig(pp=2),
                                   ShapeConfig("t", "train", 16, 4))
@@ -43,7 +44,7 @@ def test_pp_config_on_flat_mesh_raises():
 
 def test_cp_config_on_flat_mesh_raises():
     model = _tiny_model()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="cp"):
         step_mod.build_train_step(model, mesh, ParallelConfig(cp=2),
                                   ShapeConfig("t", "train", 16, 4))
@@ -201,14 +202,14 @@ def test_resolve_cp_mode_auto():
 def test_kernel_impl_env_override(monkeypatch):
     from repro.kernels import ops as kops
     monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
-    assert kops._resolve("ref") == "ref"
-    assert kops._resolve("auto") in ("ref", "pallas")
+    assert kops.resolve("ref") == "ref"
+    assert kops.resolve("auto") in ("ref", "pallas")
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas_interpret")
-    assert kops._resolve("ref") == "pallas_interpret"
-    assert kops._resolve("auto") == "pallas_interpret"
+    assert kops.resolve("ref") == "pallas_interpret"
+    assert kops.resolve("auto") == "pallas_interpret"
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "cuda")
     with pytest.raises(ValueError, match="REPRO_KERNEL_IMPL"):
-        kops._resolve("auto")
+        kops.resolve("auto")
 
 
 # ---- attention impl plumbing --------------------------------------------- #
