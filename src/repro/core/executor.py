@@ -39,6 +39,8 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.analysis.affinity import record as _affinity_record
 from repro.core.graph import SectionGraph
 from repro.core.messages import MessageQueue
@@ -221,10 +223,13 @@ class StreamSession:
     # ------------------------------------------------------------------ #
     @staticmethod
     def _timed(st: _IterationState, d: Dispatch):
+        """The task body as the worker runs it; under a profiler it is the
+        host span ``<section>:<tag>``."""
         def timed():
             _task_local.slot = {"start": time.perf_counter()}
             _affinity_record(d.section)
-            return _block(d.fn())
+            with TraceAnnotation(f"{d.section}:{d.tag}"):
+                return _block(d.fn())
         return timed
 
     def _sink(self, st: _IterationState, d: Dispatch):
@@ -363,10 +368,12 @@ def order_samples(samples: Sequence[Sample], *, reorder: bool = True
     """The per-iteration sample dispatch order: wavefront (Algorithm 1 on
     cost-model 6-tuples) when ``reorder``, else FIFO.  Returns the
     permutation (original sample indices in dispatch order) and the
-    ScheduleResult (None for FIFO)."""
+    ScheduleResult (None for FIFO).  Under a profiler, Algorithm 1 is the
+    host span ``wavefront``."""
     if not reorder:
         return list(range(len(samples))), None
-    res = wavefront_schedule(samples)
+    with TraceAnnotation("wavefront"):
+        res = wavefront_schedule(samples)
     return [s.idx for s in res.order], res
 
 

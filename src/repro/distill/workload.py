@@ -60,33 +60,37 @@ def _cp_ctx(mesh, *cfgs):
 
 def teacher_hidden(params_t, t_cfg: ArchConfig, tokens, *, impl="auto",
                    remat=True):
-    """Teacher body forward: final hidden states (no unembedding)."""
-    h, _ = tf.lm_forward(params_t, t_cfg, {"tokens": tokens},
-                         impl=impl, remat=remat, logits_out=False)
-    return h
+    """Teacher body forward: final hidden states (no unembedding), under
+    the scope ``teacher``."""
+    with jax.named_scope("teacher"):
+        h, _ = tf.lm_forward(params_t, t_cfg, {"tokens": tokens},
+                             impl=impl, remat=remat, logits_out=False)
+        return h
 
 
 def distill_loss(params_s, s_cfg: ArchConfig, batch, h_teacher,
                  teacher_unembed, *, alpha: float = 0.5,
                  temperature: float = 2.0, impl="auto", remat=True,
                  kl_impl="auto"):
-    """CE + α·T²·KL from hidden states (teacher output layer colocated)."""
-    h_s, aux = tf.lm_forward(params_s, s_cfg, batch, impl=impl,
-                             remat=remat, logits_out=False)
-    logits = tf.unembed(params_s, s_cfg, h_s)
-    ce = cm.cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
-    B, S, Ds = h_s.shape
-    w_s = (params_s["embed"].T if s_cfg.tie_embeddings
-           else params_s["unembed"])
-    mask = batch.get("loss_mask")
-    kl = kops.distill_kl(
-        h_s.reshape(B * S, Ds), w_s,
-        jax.lax.stop_gradient(h_teacher).reshape(B * S, -1),
-        jax.lax.stop_gradient(teacher_unembed),
-        mask=None if mask is None else mask.reshape(B * S),
-        temperature=temperature, impl=kl_impl)
-    loss = (1 - alpha) * ce + alpha * (temperature ** 2) * kl
-    return loss, {"ce": ce, "kl": kl, "aux": aux}
+    """CE + α·T²·KL from hidden states (teacher output layer colocated),
+    under the scope ``student``."""
+    with jax.named_scope("student"):
+        h_s, aux = tf.lm_forward(params_s, s_cfg, batch, impl=impl,
+                                 remat=remat, logits_out=False)
+        logits = tf.unembed(params_s, s_cfg, h_s)
+        ce = cm.cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+        B, S, Ds = h_s.shape
+        w_s = (params_s["embed"].T if s_cfg.tie_embeddings
+               else params_s["unembed"])
+        mask = batch.get("loss_mask")
+        kl = kops.distill_kl(
+            h_s.reshape(B * S, Ds), w_s,
+            jax.lax.stop_gradient(h_teacher).reshape(B * S, -1),
+            jax.lax.stop_gradient(teacher_unembed),
+            mask=None if mask is None else mask.reshape(B * S),
+            temperature=temperature, impl=kl_impl)
+        loss = (1 - alpha) * ce + alpha * (temperature ** 2) * kl
+        return loss, {"ce": ce, "kl": kl, "aux": aux}
 
 
 # --------------------------------------------------------------------------- #
@@ -163,16 +167,21 @@ def build_colocated_step(t_cfg: ArchConfig, s_cfg: ArchConfig, mesh: Mesh,
             def micro(carry, mb):
                 g_acc, l_acc = carry
                 (loss, _), grads = grad_fn(params_s, mb, params_t)
-                g_acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32), g_acc, grads)
+                with jax.named_scope("grad_accum"):
+                    g_acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(jnp.float32), g_acc,
+                        grads)
                 return (g_acc, l_acc + loss), None
 
-            g0 = jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), params_s)
+            with jax.named_scope("grad_accum"):
+                g0 = jax.tree_util.tree_map(
+                    lambda x: jnp.zeros(x.shape, jnp.float32), params_s)
             (g_sum, l_sum), _ = jax.lax.scan(micro, (g0, jnp.float32(0)),
                                              mbs_tree)
-            grads = jax.tree_util.tree_map(
-                lambda g, p: (g / n_micro).astype(p.dtype), g_sum, params_s)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree_util.tree_map(
+                    lambda g, p: (g / n_micro).astype(p.dtype), g_sum,
+                    params_s)
             loss = l_sum / n_micro
         lr = lr_fn(step_idx)
         new_p, new_opt, gnorm = adamw.update(grads, opt_state, lr, opt_cfg)

@@ -188,89 +188,97 @@ def _flash_bwd_core(causal, window, scale, blocks, res, do, dlse):
     only ``o`` was consumed).  The FlashAttention-2 backward's per-row term
     ``delta_i = Σ_d do_id·o_id`` generalizes to ``delta_i − dlse_i`` when the
     lse is itself differentiated — d lse_i/d s_ij = p_ij, so the joint
-    cotangent of s_ij is p_ij·(dp_ij − delta_i + dlse_i)."""
-    q, k, v, o, lse, seg_q, seg_kv, q_offset, kv_pos = res
-    block_q, block_kv = blocks
-    B, S, H, D = q.shape
-    _, T, KV, _ = k.shape
-    G = H // KV
-    nq, nkv = S // block_q, T // block_kv
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    of = o.astype(jnp.float32)
-    # delta [B,S,H]
-    delta = jnp.sum(dof * of, axis=-1)
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
+    cotangent of s_ij is p_ij·(dp_ij − delta_i + dlse_i).  Its device ops
+    carry the scope ``flash_bwd``."""
+    with jax.named_scope("flash_bwd"):
+        q, k, v, o, lse, seg_q, seg_kv, q_offset, kv_pos = res
+        block_q, block_kv = blocks
+        B, S, H, D = q.shape
+        _, T, KV, _ = k.shape
+        G = H // KV
+        nq, nkv = S // block_q, T // block_kv
+        qf = q.astype(jnp.float32)
+        kf = k.astype(jnp.float32)
+        vf = v.astype(jnp.float32)
+        dof = do.astype(jnp.float32)
+        of = o.astype(jnp.float32)
+        # delta [B,S,H]
+        delta = jnp.sum(dof * of, axis=-1)
+        if dlse is not None:
+            delta = delta - dlse.astype(jnp.float32)
 
-    qr = qf.reshape(B, nq, block_q, KV, G, D)
-    dor = dof.reshape(B, nq, block_q, KV, G, D)
-    lser = lse.reshape(B, nq, block_q, KV, G)
-    dltr = delta.reshape(B, nq, block_q, KV, G)
-    kr = kf.reshape(B, nkv, block_kv, KV, D)
-    vr = vf.reshape(B, nkv, block_kv, KV, D)
-    sq_r = (seg_q.reshape(B, nq, block_q).transpose(1, 0, 2)
-            if seg_q is not None else jnp.zeros((nq, 1, 1), jnp.int32))
-    skv_r = (seg_kv.reshape(B, nkv, block_kv).transpose(1, 0, 2)
-             if seg_kv is not None else jnp.zeros((nkv, 1, 1), jnp.int32))
-    kvp_r = (kv_pos.reshape(nkv, block_kv) if kv_pos is not None
-             else jnp.zeros((nkv, 1), jnp.int32))
+        qr = qf.reshape(B, nq, block_q, KV, G, D)
+        dor = dof.reshape(B, nq, block_q, KV, G, D)
+        lser = lse.reshape(B, nq, block_q, KV, G)
+        dltr = delta.reshape(B, nq, block_q, KV, G)
+        kr = kf.reshape(B, nkv, block_kv, KV, D)
+        vr = vf.reshape(B, nkv, block_kv, KV, D)
+        sq_r = (seg_q.reshape(B, nq, block_q).transpose(1, 0, 2)
+                if seg_q is not None else jnp.zeros((nq, 1, 1), jnp.int32))
+        skv_r = (seg_kv.reshape(B, nkv, block_kv).transpose(1, 0, 2)
+                 if seg_kv is not None else jnp.zeros((nkv, 1, 1), jnp.int32))
+        kvp_r = (kv_pos.reshape(nkv, block_kv) if kv_pos is not None
+                 else jnp.zeros((nkv, 1), jnp.int32))
 
-    dk0 = jnp.zeros((nkv, B, block_kv, KV, D), jnp.float32)
-    dv0 = jnp.zeros((nkv, B, block_kv, KV, D), jnp.float32)
+        dk0 = jnp.zeros((nkv, B, block_kv, KV, D), jnp.float32)
+        dv0 = jnp.zeros((nkv, B, block_kv, KV, D), jnp.float32)
 
-    # Outer scan over q blocks carries full dk/dv accumulators; the inner scan
-    # over kv blocks emits per-(q,kv)-block dk/dv contributions.
-    def outer2(carry, qin):
-        dk_acc, dv_acc = carry
-        qi, q_blk, do_blk, lse_blk, dlt_blk, sq_blk = qin
-        q_pos = q_offset + qi * block_q + jnp.arange(block_q)
+        # Outer scan over q blocks carries full dk/dv accumulators; the
+        # inner scan over kv blocks emits per-(q,kv)-block dk/dv
+        # contributions.
+        def outer2(carry, qin):
+            dk_acc, dv_acc = carry
+            qi, q_blk, do_blk, lse_blk, dlt_blk, sq_blk = qin
+            q_pos = q_offset + qi * block_q + jnp.arange(block_q)
 
-        def inner(dq_acc, kin):
-            ki, k_blk, v_blk, skv_blk, kvp_blk = kin
-            kv_pos_b = (kvp_blk if kv_pos is not None
-                        else ki * block_kv + jnp.arange(block_kv))
-            s = jnp.einsum("bqkgd,btkd->bkgqt", q_blk * scale, k_blk)
-            msk = _block_mask(q_pos, kv_pos_b, causal, window,
-                              sq_blk if seg_q is not None else None,
-                              skv_blk if seg_kv is not None else None)
-            msk = (msk[None, None, None] if msk.ndim == 2
-                   else msk[:, None, None])
-            s = jnp.where(msk, s, NEG_INF)
-            p = jnp.exp(s - lse_blk.transpose(0, 2, 3, 1)[..., None])
-            dp = jnp.einsum("bqkgd,btkd->bkgqt", do_blk, v_blk)
-            ds = p * (dp - dlt_blk.transpose(0, 2, 3, 1)[..., None]) * scale
-            dq_acc = dq_acc + jnp.einsum("bkgqt,btkd->bqkgd", ds, k_blk)
-            dk_b = jnp.einsum("bkgqt,bqkgd->btkd", ds, q_blk)
-            dv_b = jnp.einsum("bkgqt,bqkgd->btkd", p, do_blk)
-            return dq_acc, (dk_b, dv_b)
+            def inner(dq_acc, kin):
+                ki, k_blk, v_blk, skv_blk, kvp_blk = kin
+                kv_pos_b = (kvp_blk if kv_pos is not None
+                            else ki * block_kv + jnp.arange(block_kv))
+                s = jnp.einsum("bqkgd,btkd->bkgqt", q_blk * scale, k_blk)
+                msk = _block_mask(q_pos, kv_pos_b, causal, window,
+                                  sq_blk if seg_q is not None else None,
+                                  skv_blk if seg_kv is not None else None)
+                msk = (msk[None, None, None] if msk.ndim == 2
+                       else msk[:, None, None])
+                s = jnp.where(msk, s, NEG_INF)
+                p = jnp.exp(s - lse_blk.transpose(0, 2, 3, 1)[..., None])
+                dp = jnp.einsum("bqkgd,btkd->bkgqt", do_blk, v_blk)
+                ds = (p * (dp - dlt_blk.transpose(0, 2, 3, 1)[..., None])
+                      * scale)
+                dq_acc = dq_acc + jnp.einsum("bkgqt,btkd->bqkgd", ds, k_blk)
+                dk_b = jnp.einsum("bkgqt,bqkgd->btkd", ds, q_blk)
+                dv_b = jnp.einsum("bkgqt,bqkgd->btkd", p, do_blk)
+                return dq_acc, (dk_b, dv_b)
 
-        dq0 = jnp.zeros((B, block_q, KV, G, D), jnp.float32)
-        dq, (dk_b, dv_b) = jax.lax.scan(
-            inner, dq0,
-            (jnp.arange(nkv), kr.transpose(1, 0, 2, 3, 4),
-             vr.transpose(1, 0, 2, 3, 4), skv_r, kvp_r))
-        return (dk_acc + dk_b, dv_acc + dv_b), dq
+            dq0 = jnp.zeros((B, block_q, KV, G, D), jnp.float32)
+            dq, (dk_b, dv_b) = jax.lax.scan(
+                inner, dq0,
+                (jnp.arange(nkv), kr.transpose(1, 0, 2, 3, 4),
+                 vr.transpose(1, 0, 2, 3, 4), skv_r, kvp_r))
+            return (dk_acc + dk_b, dv_acc + dv_b), dq
 
-    (dk_all, dv_all), dq_all = jax.lax.scan(
-        outer2, (dk0, dv0),
-        (jnp.arange(nq), qr.transpose(1, 0, 2, 3, 4, 5),
-         dor.transpose(1, 0, 2, 3, 4, 5),
-         lser.transpose(1, 0, 2, 3, 4), dltr.transpose(1, 0, 2, 3, 4), sq_r))
-    dq = dq_all.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, D).astype(q.dtype)
-    dk = dk_all.transpose(1, 0, 2, 3, 4).reshape(B, T, KV, D).astype(k.dtype)
-    dv = dv_all.transpose(1, 0, 2, 3, 4).reshape(B, T, KV, D).astype(v.dtype)
+        (dk_all, dv_all), dq_all = jax.lax.scan(
+            outer2, (dk0, dv0),
+            (jnp.arange(nq), qr.transpose(1, 0, 2, 3, 4, 5),
+             dor.transpose(1, 0, 2, 3, 4, 5),
+             lser.transpose(1, 0, 2, 3, 4), dltr.transpose(1, 0, 2, 3, 4),
+             sq_r))
+        dq = dq_all.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, D).astype(
+            q.dtype)
+        dk = dk_all.transpose(1, 0, 2, 3, 4).reshape(B, T, KV, D).astype(
+            k.dtype)
+        dv = dv_all.transpose(1, 0, 2, 3, 4).reshape(B, T, KV, D).astype(
+            v.dtype)
 
-    def zgrad(x):
-        if x is None:
-            return None
-        shape = getattr(x, "shape", ())
-        return np.zeros(shape, jax.dtypes.float0)
+        def zgrad(x):
+            if x is None:
+                return None
+            shape = getattr(x, "shape", ())
+            return np.zeros(shape, jax.dtypes.float0)
 
-    return (dq, dk, dv, zgrad(seg_q), zgrad(seg_kv), zgrad(q_offset),
-            zgrad(kv_pos))
+        return (dq, dk, dv, zgrad(seg_q), zgrad(seg_kv), zgrad(q_offset),
+                zgrad(kv_pos))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -57,21 +57,26 @@ LM_KEYS = ("tokens", "labels", "loss_mask", "image_pos", "image_valid")
 def vit_forward(pv, vit_cfg: ArchConfig, patches, valid, *,
                 impl: str = "auto", remat: bool = True):
     """ViT-encode the gathered image samples of one microbatch and mask
-    padding rows.  patches [cap, P, pd], valid [cap] → emb [cap, K, Vd]."""
-    emb = vlm.vit_encode(pv, vit_cfg, patches, impl=impl, remat=remat)
-    return emb * valid[:, None, None].astype(emb.dtype)
+    padding rows, under the scope ``vit``.  patches [cap, P, pd], valid
+    [cap] → emb [cap, K, Vd]."""
+    with jax.named_scope("vit"):
+        emb = vlm.vit_encode(pv, vit_cfg, patches, impl=impl, remat=remat)
+        return emb * valid[:, None, None].astype(emb.dtype)
 
 
 def lm_microbatch_loss(pl, model: Model, mb: dict, emb, vidx):
     """LM loss of one microbatch: scatter the (masked) vision embeddings
     back into per-sample image slots, then the backbone loss with
-    image-slot injection.  emb [cap, K, Vd], vidx [cap] local indices."""
-    mbs_n = mb["tokens"].shape[0]
-    img = jnp.zeros((mbs_n,) + emb.shape[1:], emb.dtype).at[vidx].add(emb)
-    lmb = {k: mb[k] for k in LM_KEYS if k in mb}
-    lmb["image_embeds"] = img
-    loss, _ = model.loss(pl, lmb)
-    return loss
+    image-slot injection, under the scope ``lm``.  emb [cap, K, Vd], vidx
+    [cap] local indices."""
+    with jax.named_scope("lm"):
+        mbs_n = mb["tokens"].shape[0]
+        img = jnp.zeros((mbs_n,) + emb.shape[1:], emb.dtype).at[vidx].add(
+            emb)
+        lmb = {k: mb[k] for k in LM_KEYS if k in mb}
+        lmb["image_embeds"] = img
+        loss, _ = model.loss(pl, lmb)
+        return loss
 
 
 # --------------------------------------------------------------------------- #
@@ -104,16 +109,18 @@ def build_plan(order: Sequence[int], has_image: np.ndarray, mbs: int,
 def colocated_batch(batch: dict, plan: IterationPlan) -> dict:
     """Lay one global batch out for the colocated oracle: permute into the
     plan's dispatch order and pre-split into [n_mb, mbs, ...] so the
-    oracle's scan sees exactly the executor's microbatch composition."""
-    idx = list(plan.order)
-    out = {}
-    for k in LM_KEYS + ("patches",):
-        v = np.asarray(batch[k])[idx]
-        out[k] = jnp.asarray(
-            v.reshape((plan.n_mb, plan.mbs) + v.shape[1:]))
-    out["vis_idx"] = jnp.asarray(plan.vis_idx)
-    out["vis_valid"] = jnp.asarray(plan.vis_valid)
-    return out
+    oracle's scan sees exactly the executor's microbatch composition.
+    Under a profiler it is the host span ``layout``."""
+    with jax.profiler.TraceAnnotation("layout"):
+        idx = list(plan.order)
+        out = {}
+        for k in LM_KEYS + ("patches",):
+            v = np.asarray(batch[k])[idx]
+            out[k] = jnp.asarray(
+                v.reshape((plan.n_mb, plan.mbs) + v.shape[1:]))
+        out["vis_idx"] = jnp.asarray(plan.vis_idx)
+        out["vis_valid"] = jnp.asarray(plan.vis_valid)
+        return out
 
 
 # --------------------------------------------------------------------------- #
@@ -171,17 +178,20 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
             g_acc, l_acc = carry
             mb, vidx, vval = xs
             loss, g = grad_fn(params, mb, vidx, vval)
-            g_acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(jnp.float32), g_acc, g)
+            with jax.named_scope("grad_accum"):
+                g_acc = jax.tree_util.tree_map(
+                    lambda a, b: a + b.astype(jnp.float32), g_acc, g)
             return (g_acc, l_acc + loss), None
 
-        g0 = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), params)
+        with jax.named_scope("grad_accum"):
+            g0 = jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape, jnp.float32), params)
         (g_sum, l_sum), _ = jax.lax.scan(
             body, (g0, jnp.float32(0)),
             (mbs_tree, batch["vis_idx"], batch["vis_valid"]))
-        grads = jax.tree_util.tree_map(
-            lambda g, p: (g / n_mb).astype(p.dtype), g_sum, params)
+        with jax.named_scope("grad_accum"):
+            grads = jax.tree_util.tree_map(
+                lambda g, p: (g / n_mb).astype(p.dtype), g_sum, params)
         loss = l_sum / n_mb
         lr = lr_fn(step_idx)
         new_p, new_opt, gnorm = adamw.update(grads, opt_state, lr, opt_cfg)

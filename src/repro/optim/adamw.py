@@ -105,6 +105,7 @@ def update(grads, state: AdamWState, lr: jnp.ndarray,
     per-section updates clip exactly like one colocated update would.
     Passing it with clipping disabled raises: the caller clearly expects
     the joint norm to drive the update, and it would be silently ignored.
+    Its device ops carry the scope ``optimizer``.
     """
     check_live(state, "optimizer state")
     if gnorm is not None and cfg.clip_norm <= 0:
@@ -112,35 +113,36 @@ def update(grads, state: AdamWState, lr: jnp.ndarray,
             f"adamw.update: gnorm= was passed but clipping is disabled "
             f"(clip_norm={cfg.clip_norm}) — the precomputed joint norm "
             "would be silently ignored; enable clip_norm or drop gnorm=")
-    if gnorm is None:
-        gnorm = global_norm(grads)
-    scale = jnp.where(gnorm > cfg.clip_norm, cfg.clip_norm / gnorm, 1.0) \
-        if cfg.clip_norm > 0 else jnp.float32(1.0)
-    step = state.step + 1
-    t = step.astype(jnp.float32)
-    bc1 = 1.0 - cfg.b1 ** t
-    bc2 = 1.0 - cfg.b2 ** t
+    with jax.named_scope("optimizer"):
+        if gnorm is None:
+            gnorm = global_norm(grads)
+        scale = jnp.where(gnorm > cfg.clip_norm, cfg.clip_norm / gnorm, 1.0) \
+            if cfg.clip_norm > 0 else jnp.float32(1.0)
+        step = state.step + 1
+        t = step.astype(jnp.float32)
+        bc1 = 1.0 - cfg.b1 ** t
+        bc2 = 1.0 - cfg.b2 ** t
 
-    def upd(g, mu, nu, m):
-        g = g.astype(jnp.float32) * scale
-        mu = cfg.b1 * mu + (1 - cfg.b1) * g
-        nu = cfg.b2 * nu + (1 - cfg.b2) * jnp.square(g)
-        mu_hat = mu / bc1
-        nu_hat = nu / bc2
-        step_v = mu_hat / (jnp.sqrt(nu_hat) + cfg.eps)
-        m = m - lr * (step_v + cfg.weight_decay * m)
-        return mu, nu, m
+        def upd(g, mu, nu, m):
+            g = g.astype(jnp.float32) * scale
+            mu = cfg.b1 * mu + (1 - cfg.b1) * g
+            nu = cfg.b2 * nu + (1 - cfg.b2) * jnp.square(g)
+            mu_hat = mu / bc1
+            nu_hat = nu / bc2
+            step_v = mu_hat / (jnp.sqrt(nu_hat) + cfg.eps)
+            m = m - lr * (step_v + cfg.weight_decay * m)
+            return mu, nu, m
 
-    flat_g, treedef = jax.tree_util.tree_flatten(grads)
-    flat_mu = treedef.flatten_up_to(state.mu)
-    flat_nu = treedef.flatten_up_to(state.nu)
-    flat_ma = treedef.flatten_up_to(state.master)
-    out = [upd(g, mu, nu, m) for g, mu, nu, m in
-           zip(flat_g, flat_mu, flat_nu, flat_ma)]
-    mu = jax.tree_util.tree_unflatten(treedef, [o[0] for o in out])
-    nu = jax.tree_util.tree_unflatten(treedef, [o[1] for o in out])
-    master = jax.tree_util.tree_unflatten(treedef, [o[2] for o in out])
-    new_params = jax.tree_util.tree_map(
-        lambda m, g: m.astype(g.dtype) if g.dtype != jnp.float32 else m,
-        master, grads)
-    return new_params, AdamWState(step, mu, nu, master), gnorm
+        flat_g, treedef = jax.tree_util.tree_flatten(grads)
+        flat_mu = treedef.flatten_up_to(state.mu)
+        flat_nu = treedef.flatten_up_to(state.nu)
+        flat_ma = treedef.flatten_up_to(state.master)
+        out = [upd(g, mu, nu, m) for g, mu, nu, m in
+               zip(flat_g, flat_mu, flat_nu, flat_ma)]
+        mu = jax.tree_util.tree_unflatten(treedef, [o[0] for o in out])
+        nu = jax.tree_util.tree_unflatten(treedef, [o[1] for o in out])
+        master = jax.tree_util.tree_unflatten(treedef, [o[2] for o in out])
+        new_params = jax.tree_util.tree_map(
+            lambda m, g: m.astype(g.dtype) if g.dtype != jnp.float32 else m,
+            master, grads)
+        return new_params, AdamWState(step, mu, nu, master), gnorm

@@ -7,10 +7,13 @@ cross-section dependencies; dispatch equals FIFO when wavefront
 reordering is disabled; partition_global_batch / merge_fanout_schedules
 compose with the executor under dp>1 fanout; SectionWorker failures stay
 scoped to the failing task."""
+import glob
+import os
 import time
 
 import pytest
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -116,6 +119,40 @@ def test_completion_order_respects_cross_section_dependencies():
             assert res.results[("c", f"c{i}")] == float(i)
         assert res.makespan > 0.0
         assert 0.0 < res.utilization("c") <= 1.0
+
+
+def _host_spans(log_dir) -> set:
+    """Names of the events on the host planes of the trace under
+    ``log_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_profiled_iteration_shows_program_spans(tmp_path):
+    """Under a profiler one MLLM iteration's host work is on the trace's
+    clock: Algorithm 1 (``wavefront``), the colocated layout (``layout``)
+    and every executor task of both sections (``<section>:<tag>``)."""
+    from repro.mllm import workload as mllm
+    samples = hetero_samples()
+    has = np.array([s.t_f_bc > 0 for s in samples])
+    batch = {k: np.zeros((8, 4), np.int32) for k in mllm.LM_KEYS}
+    batch["patches"] = np.zeros((8, 4, 3), np.float32)
+    with CompoundExecutor(sections=["bc", "c"]) as ex:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            order, sched = order_samples(samples, reorder=True)
+            mllm.colocated_batch(batch, mllm.build_plan(order, has, 2,
+                                                        sched))
+            ex.run(_producer_consumer_dispatches(ex, order, it="s"))
+        finally:
+            jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    assert {"wavefront", "layout"} <= spans
+    assert {f"bc:p{i}" for i in order} | {f"c:c{i}" for i in order} <= spans
 
 
 def test_fifo_mode_realizes_incoming_order():
