@@ -7,8 +7,9 @@ never stalls on vision work.  Two execution modes share one arithmetic:
 
 * :func:`build_colocated_step` — ONE jit: scan over microbatches, each
   doing ViT-encode of its image samples (gathered to the static per-
-  microbatch capacity) + LM loss with image-slot injection, grads
-  accumulated in microbatch order.  This is the numerical oracle.
+  microbatch capacity, and only where the microbatch holds an image) +
+  LM loss with image-slot injection, grads accumulated in microbatch
+  order.  This is the numerical oracle.
 * :class:`MLLMRuntime` — disaggregated on the compound executor: the ViT
   section runs fwd/bwd tasks for *image-bearing microbatches only* on its
   own carved mesh, embeddings / embedding-cotangents cross the
@@ -16,16 +17,17 @@ never stalls on vision work.  Two execution modes share one arithmetic:
   the wavefront dispatch order.
 
 Because both modes perform the same per-microbatch computations in the
-same order (the dynamic path only *skips* work whose contribution is an
-exact zero), the disaggregated per-step loss and grads match the
+same order (both only *skip* vision work whose contribution is an exact
+zero), the disaggregated per-step loss and grads match the
 colocated oracle bit-for-bit on equal section layouts — driver-verified
 on mixed and all-text batches (``tests/drivers/driver_mllm_runtime.py``).
 
 Static vs dynamic shapes: each microbatch has a *static* vision capacity
 (= its sample count); image samples are gathered into that capacity and
-zero-padded — padding only ever exists inside a microbatch.  Whether a
-microbatch dispatches vision work at all is dynamic (data-dependent
-activation).
+zero-padded — padding only ever exists inside a microbatch that holds
+an image.  Whether a microbatch runs vision work at all is dynamic
+(data-dependent activation): a task the executor does not dispatch in
+:class:`MLLMRuntime`, a ``lax.cond`` branch in the colocated step.
 """
 from __future__ import annotations
 
@@ -134,8 +136,11 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
     """One jit over the pre-microbatched batch from
     :func:`colocated_batch`: per microbatch, ViT fwd on the gathered image
     samples + LM loss, per-microbatch joint grads accumulated in dispatch
-    order, one AdamW update.  ``params`` and ``opt_state`` are donated.
-    Returns (step, shardings)."""
+    order, one AdamW update.  A microbatch with no image skips the ViT
+    (forward and backward) under a ``lax.cond`` and hands the LM zeros,
+    which is what the masked ViT output would be; the metric ``vit_mbs``
+    counts the microbatches whose ViT ran.  ``params`` and ``opt_state``
+    are donated.  Returns (step, shardings)."""
     model = build_model(lm_cfg, impl=impl)
     v_specs = vlm.vit_specs(vit_cfg)
     l_specs = model.specs()
@@ -162,10 +167,18 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
     lr_fn = lr_schedule or functools.partial(schedules.constant,
                                              peak_lr=1e-3)
 
-    def joint_loss(ps, mb, vidx, vval):
+    emb_shape = (mbs, lm_cfg.max_image_tokens, lm_cfg.vision_dim)
+
+    def joint_loss(ps, mb, vidx, vval, vit_on):
         with cm.act_hook(*hook):
             sub = mb["patches"][vidx]
-            emb = vit_forward(ps["vit"], vit_cfg, sub, vval, impl=impl)
+            # an imageless microbatch's masked ViT output is exact zeros
+            # and its ViT gradient an exact zero: skip computing them
+            emb = jax.lax.cond(
+                vit_on,
+                lambda pv, x, v: vit_forward(pv, vit_cfg, x, v, impl=impl),
+                lambda pv, x, v: jnp.zeros(emb_shape, pv["merge_proj"].dtype),
+                ps["vit"], sub, vval)
             return lm_microbatch_loss(ps["lm"], model, mb, emb, vidx)
 
     grad_fn = jax.value_and_grad(joint_loss)
@@ -173,11 +186,11 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
     def step(params, opt_state, batch, step_idx):
         n_mb = batch["tokens"].shape[0]
         mbs_tree = {k: batch[k] for k in LM_KEYS + ("patches",)}
+        vit_on = jnp.any(batch["vis_valid"] > 0, axis=1)
 
         def body(carry, xs):
             g_acc, l_acc = carry
-            mb, vidx, vval = xs
-            loss, g = grad_fn(params, mb, vidx, vval)
+            loss, g = grad_fn(params, *xs)
             with jax.named_scope("grad_accum"):
                 g_acc = jax.tree_util.tree_map(
                     lambda a, b: a + b.astype(jnp.float32), g_acc, g)
@@ -188,7 +201,7 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
                 lambda x: jnp.zeros(x.shape, jnp.float32), params)
         (g_sum, l_sum), _ = jax.lax.scan(
             body, (g0, jnp.float32(0)),
-            (mbs_tree, batch["vis_idx"], batch["vis_valid"]))
+            (mbs_tree, batch["vis_idx"], batch["vis_valid"], vit_on))
         with jax.named_scope("grad_accum"):
             grads = jax.tree_util.tree_map(
                 lambda g, p: (g / n_mb).astype(p.dtype), g_sum, params)
@@ -196,12 +209,12 @@ def build_colocated_step(vit_cfg: ArchConfig, lm_cfg: ArchConfig,
         lr = lr_fn(step_idx)
         new_p, new_opt, gnorm = adamw.update(grads, opt_state, lr, opt_cfg)
         metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
-                   "lr": lr}
+                   "lr": lr, "vit_mbs": jnp.sum(vit_on, dtype=jnp.int32)}
         if return_grads:
             metrics["grads"] = grads
         return new_p, new_opt, metrics
 
-    out_metrics = {"loss": rep, "grad_norm": rep, "lr": rep}
+    out_metrics = {"loss": rep, "grad_norm": rep, "lr": rep, "vit_mbs": rep}
     if return_grads:
         out_metrics["grads"] = p_shard
     # params and optimizer state are donated: at published widths two
