@@ -24,6 +24,7 @@ import warnings
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 
@@ -162,6 +163,35 @@ def distill_kl(h_student, w_student, h_teacher, w_teacher, *, mask=None,
                                         w_teacher, mask=mask,
                                         temperature=temperature)
     raise ValueError(f"unknown distill_kl impl {impl!r}")
+
+
+# --------------------------------------------------------------------------- #
+def embed_impl(impl: str, V: int, d: int, *, block_v: int = 512) -> str:
+    """:func:`resolve` for the gradient of a ``[V, d]`` table's lookup.
+    Under a kernel layout over several devices the lookup keeps XLA's
+    scatter (``ref``): the kernel is not partitioned over a mesh."""
+    from repro.kernels import embed_grad as eg
+    from repro.models import common as cm
+    layout = cm._KERNEL_LAYOUT
+    if layout is not None and layout.mesh.size > 1:
+        return "ref"
+    return resolve(impl, fits=eg.fits(V, d, block_v),
+                   what=f"embed_grad V={V} d={d}")
+
+
+def embed_lookup(table, ids, *, impl: str = "auto", block_v: int = 512):
+    """``jnp.take(table, ids, axis=0)``.  The Pallas tiers differentiate
+    it with the sorted one-hot contraction of ``embed_grad.py``; ``ref``
+    with XLA's scatter-add."""
+    V, d = table.shape
+    impl = embed_impl(impl, V, d, block_v=block_v)
+    if impl in _PALLAS:
+        from repro.kernels import embed_grad as eg
+        return eg.embed_lookup(table, ids, block_v=block_v,
+                               interpret=(impl == "pallas_interpret"))
+    if impl in ("ref", "ref_naive"):
+        return jnp.take(table, ids, axis=0)
+    raise ValueError(f"unknown embed_lookup impl {impl!r}")
 
 
 # --------------------------------------------------------------------------- #

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import ArchConfig
+from repro.kernels import ops as kops
 from repro.models import attention as att
 from repro.models import common as cm
 from repro.models import mamba as mb
@@ -193,8 +194,9 @@ def vision_scatter(p, cfg: ArchConfig, x: jnp.ndarray,
     return x
 
 
-def embed_tokens(p, cfg: ArchConfig, batch: dict) -> jnp.ndarray:
-    x = jnp.take(p["embed"], batch["tokens"], axis=0)
+def embed_tokens(p, cfg: ArchConfig, batch: dict, *,
+                 impl: str = "auto") -> jnp.ndarray:
+    x = kops.embed_lookup(p["embed"], batch["tokens"], impl=impl)
     return vision_scatter(p, cfg, x, batch)
 
 
@@ -203,7 +205,7 @@ def lm_forward(p, cfg: ArchConfig, batch: dict, *, causal: bool = True,
                logits_out: bool = True):
     """Full-sequence forward. Returns (logits_or_hidden, aux_loss)."""
     pk, reps = group_layout(cfg)
-    x = cm.shard_act(embed_tokens(p, cfg, batch), "hidden")
+    x = cm.shard_act(embed_tokens(p, cfg, batch, impl=impl), "hidden")
     segment_ids = batch.get("segment_ids")
 
     def period_body(x, period_params):

@@ -13,6 +13,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import distill_kl_pallas as dkp
+from repro.kernels import embed_grad as eg
 from repro.kernels import flash_attention as fa
 from repro.kernels import ssd_pallas
 
@@ -105,3 +106,21 @@ def test_ssd_scan_compiles(one_chip):
             x, dt, A, B, C, D, chunk=128),
         s_((b, s, h, p)), s_((b, s, h), jnp.float32), s_((h,), jnp.float32),
         s_((b, s, n)), s_((b, s, n)), s_((h,), jnp.float32))
+
+
+# (V, d, mbs, S): Pixtral-12B's cut vocabulary and Granite-3.0-2B's tied
+# one, each with a microbatch of 2 × 4096 ids
+EMBED = {"pixtral-12b": (16384, 5120, 2, 4096),
+         "granite-3.0-2b": (49152, 2048, 2, 4096)}
+
+
+@pytest.mark.parametrize("widths", sorted(EMBED))
+def test_embed_grad_compiles(one_chip, widths):
+    V, d, mbs, S = EMBED[widths]
+    table = jax.ShapeDtypeStruct((V, d), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((mbs, S), jnp.int32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((mbs, S, d), jnp.bfloat16, sharding=one_chip)
+
+    def table_grad(t, i, g):
+        return jax.vjp(lambda t: eg.embed_lookup(t, i), t)[1](g)[0]
+    assert _compile_has_kernel(table_grad, table, ids, g)
